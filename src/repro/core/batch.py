@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import telemetry
 from . import u64 as u64m
 from .ops import ElementOps, get_ops
 from .types import ECLASS_SIMPLEX, Simplex
@@ -64,6 +65,7 @@ __all__ = [
     "reset_trace_counts",
     "host_fetch_counts",
     "reset_host_fetch_counts",
+    "fetch_span",
 ]
 
 BACKENDS = ("reference", "jnp", "pallas")
@@ -108,22 +110,31 @@ def use_backend(name: str):
         _active = prev
 
 
-# ---------------------------------------------------------- dispatch counters
-# One increment per BatchedOps op invocation (any backend) — the observable
-# the fused face sweep optimizes: Balance/Ghost evaluation must issue ONE
-# `face_sweep` dispatch per eval layer instead of 3 x (d+1) per-face ops.
-# Benchmarks and tests read/reset these around a measured region.
-_dispatch_counts: dict[str, int] = {}
-
-
+# ------------------------------------------------------------------ counters
+# Views over the program's counter registry (`repro.core.telemetry`), with
+# the keys and values they always had:
+#
+#   dispatch.<op>   one per BatchedOps op invocation (any backend): Balance/
+#                   Ghost evaluation must issue ONE `face_sweep` dispatch per
+#                   eval layer instead of 3 x (d+1) per-face ops.
+#   trace.<prog>    one per *jit trace* of a fused-eval program (bumped inside
+#                   the traced body, so cache hits cost nothing): with padded
+#                   power-of-two buckets the totals stay O(log n), and the
+#                   retrace guard asserts zero new traces when Balance re-runs
+#                   at the same bucket sizes.
+#   fetch.<stage>   one per device->host materialization of the fused eval
+#                   (`eval_2to1` / `eval_cache` / `eval_route` each fetch ONE
+#                   compacted result): at most 2 per rank per Balance round.
+#
+# Benchmarks and tests read and reset them around a measured region.
 def reset_dispatch_counts() -> None:
     """Zero the per-op dispatch counters."""
-    _dispatch_counts.clear()
+    telemetry.reset_counts("dispatch.")
 
 
 def dispatch_counts() -> dict[str, int]:
     """Snapshot of {op name: number of BatchedOps dispatches} since reset."""
-    return dict(_dispatch_counts)
+    return telemetry.counts("dispatch.")
 
 
 def count_dispatch(name: str) -> None:
@@ -131,49 +142,35 @@ def count_dispatch(name: str) -> None:
     memoize a batched-op result on immutable data (e.g. the per-Forest
     resident sweep) keep the meters' evals-per-round semantics by charging
     each reuse like the dispatch it replaces."""
-    _dispatch_counts[name] = _dispatch_counts.get(name, 0) + 1
-
-
-# Trace counters: one increment per *jit trace* of a fused-eval program
-# (bumped inside the traced body, so cache hits cost nothing).  With padded
-# power-of-two buckets the totals must stay O(log n) for the process — the
-# retrace-guard test asserts zero NEW traces when Balance re-runs at the
-# same bucket sizes.
-_trace_counts: dict[str, int] = {}
-
-# Host-fetch counters: one increment per device->host materialization on the
-# fused eval path (`eval_2to1` / `eval_cache` / `eval_route` each fetch ONE
-# compacted result).  The device_eval benchmark asserts <= 2 per rank per
-# Balance round, replacing the old per-field np.asarray fan-out.
-_host_fetch_counts: dict[str, int] = {}
+    telemetry.count("dispatch." + name)
 
 
 def reset_trace_counts() -> None:
     """Zero the fused-eval jit trace counters."""
-    _trace_counts.clear()
+    telemetry.reset_counts("trace.")
 
 
 def trace_counts() -> dict[str, int]:
     """Snapshot of {program name: jit traces} since reset."""
-    return dict(_trace_counts)
-
-
-def _bump_trace(name: str) -> None:
-    _trace_counts[name] = _trace_counts.get(name, 0) + 1
+    return telemetry.counts("trace.")
 
 
 def reset_host_fetch_counts() -> None:
     """Zero the fused-eval host materialization counters."""
-    _host_fetch_counts.clear()
+    telemetry.reset_counts("fetch.")
 
 
 def host_fetch_counts() -> dict[str, int]:
     """Snapshot of {eval stage: device->host materializations} since reset."""
-    return dict(_host_fetch_counts)
+    return telemetry.counts("fetch.")
 
 
-def _bump_fetch(name: str) -> None:
-    _host_fetch_counts[name] = _host_fetch_counts.get(name, 0) + 1
+def fetch_span(site: str, **stats):
+    """The `fetch` span around one device->host materialization on the
+    cycle's path: the host waits there for the device programs the fetched
+    arrays come from.  Dispatch before entering it, so the span holds the
+    wait and the copy only."""
+    return telemetry.span("fetch", site=site, **stats)
 
 
 class FaceSweep(NamedTuple):
@@ -228,11 +225,15 @@ def _face_sweep_fused(o: ElementOps):
 @functools.lru_cache(maxsize=None)
 def _jnp_fns(d: int, eclass: int = ECLASS_SIMPLEX):
     o = get_ops(d, eclass)
+
+    def parent_and_local_index(s):
+        return o.parent(s), o.local_index(s)
+
     return {
         "morton_key": jax.jit(o.morton_key),
         "decode": jax.jit(o.decode_key),
         "parent": jax.jit(o.parent),
-        "parent_and_local_index": jax.jit(lambda s: (o.parent(s), o.local_index(s))),
+        "parent_and_local_index": jax.jit(parent_and_local_index),
         "children": jax.jit(o.children_tm),
         "face_neighbor": jax.jit(o.face_neighbor),
         "face_sweep": jax.jit(_face_sweep_fused(o)),
@@ -464,8 +465,8 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         off = h0 & lex_lt(tgt, khi, klo, b0t, b0h, b0l)
         return off | (h1 & ~lex_lt(tgt, kh.hi, kh.lo, b1t, b1h, b1l))
 
-    def sweep_jnp(s, tree, n):
-        _bump_trace("sweep")
+    def sweep(s, tree, n):
+        telemetry.count("trace.sweep")
         m = s.level.shape[0]
         sw = _face_sweep_fused(o)(s)
         valid = sw.inside & (jnp.arange(m) < n)[None, :]
@@ -473,7 +474,7 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         return tgt, sw.key.hi, sw.key.lo, valid, sw.dual, s.level
 
     def sweep_pallas(s, tree, n):
-        _bump_trace("sweep_pallas")
+        telemetry.count("trace.sweep_pallas")
         from repro.kernels import ops as kops
 
         m = s.level.shape[0]
@@ -482,10 +483,10 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         tgt = jnp.broadcast_to(tree[None, :], (nf, m))
         return tgt, key.hi, key.lo, valid, dual, s.level
 
-    def need_fn(tgt, khi, klo, valid, lev,
+    def eval_need(tgt, khi, klo, valid, lev,
                 lt, lhi, llo, llev,
                 b0t, b0h, b0l, h0, b1t, b1h, b1l, h1):
-        _bump_trace("eval_need")
+        telemetry.count("trace.eval_need")
         m = lev.shape[0]
         lev2 = jnp.broadcast_to(lev[None, :], (nf, m))
         kh = interval_end(khi, klo, lev2)
@@ -496,10 +497,10 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         bmask = jnp.any(valid & off, axis=0)
         return need, bmask
 
-    def cache_fn(tgt, khi, klo, valid, lev,
+    def eval_cache(tgt, khi, klo, valid, lev,
                  lt, lhi, llo, llev,
                  b0t, b0h, b0l, h0, b1t, b1h, b1l, h1):
-        _bump_trace("eval_cache")
+        telemetry.count("trace.eval_cache")
         m = lev.shape[0]
         lev2 = jnp.broadcast_to(lev[None, :], (nf, m))
         kh = interval_end(khi, klo, lev2)
@@ -529,8 +530,8 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         packed = jnp.zeros((sz + 1, 7), jnp.int32).at[scat].set(cols)
         return total.sum(), packed
 
-    def route_fn(tgt, khi, klo, valid, dual, lev, mt, mhi, mlo, g):
-        _bump_trace("eval_route")
+    def eval_route(tgt, khi, klo, valid, dual, lev, mt, mhi, mlo, g):
+        telemetry.count("trace.eval_route")
         m = lev.shape[0]
         lev2 = jnp.broadcast_to(lev[None, :], (nf, m))
         kh = interval_end(khi, klo, lev2)
@@ -542,8 +543,8 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         return route_pack(tf, hf, lf, lev2.reshape(-1), dual.reshape(-1),
                           first, last, remote)
 
-    def route_pallas(tgt, khi, klo, valid, dual, lev, mt, mhi, mlo, g):
-        _bump_trace("eval_route_pallas")
+    def eval_route_pallas(tgt, khi, klo, valid, dual, lev, mt, mhi, mlo, g):
+        telemetry.count("trace.eval_route_pallas")
         from repro.kernels import ops as kops
 
         m = lev.shape[0]
@@ -557,12 +558,12 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
                           first, last, remote)
 
     return {
-        "sweep": jax.jit(sweep_jnp),
+        "sweep": jax.jit(sweep),
         "sweep_pallas": jax.jit(sweep_pallas),
-        "need": jax.jit(need_fn),
-        "cache": jax.jit(cache_fn),
-        "route": jax.jit(route_fn),
-        "route_pallas": jax.jit(route_pallas),
+        "need": jax.jit(eval_need),
+        "cache": jax.jit(eval_cache),
+        "route": jax.jit(eval_route),
+        "route_pallas": jax.jit(eval_route_pallas),
     }
 
 
@@ -590,7 +591,7 @@ class BatchedOps:
         # Empty batches short-circuit to the eager path (a Pallas grid of 0
         # tiles is invalid, and there is nothing to fuse anyway).
         if name is not None:
-            _dispatch_counts[name] = _dispatch_counts.get(name, 0) + 1
+            telemetry.count("dispatch." + name)
         return "reference" if n == 0 else self.backend
 
     def _jnp(self, name, s: Simplex, *extra):
@@ -627,7 +628,9 @@ class BatchedOps:
 
     def morton_key_np(self, s: Simplex) -> np.ndarray:
         """Host-side uint64 keys (the forest's storage format)."""
-        return u64m.to_np(self.morton_key(s))
+        key = self.morton_key(s)
+        with fetch_span("morton_key"):
+            return u64m.to_np(key)
 
     def decode(self, key: u64m.U64, level) -> Simplex:
         """Algorithm 4.8 from a level-padded key (inverse of `morton_key`)."""
@@ -955,6 +958,14 @@ class BatchedOps:
             need[ei[upd]] = True
         return need
 
+    def _eval_fetch(self, site: str, n: int, m: int):
+        """Count one device 2:1 eval's fetch; return its fetch span, whose
+        stats carry the (face, element) pairs the eval tests for real
+        (`pairs_live`, nf x n) and runs on (`pairs_padded`, nf x m, the
+        padded bucket)."""
+        telemetry.count("fetch." + site)
+        return fetch_span(site, pairs_live=self.nf * n, pairs_padded=self.nf * m)
+
     def eval_2to1(self, sw: SweepHandle | None, table: LeafTable | None,
                   mt, mk, g: int):
         """Fused interior 2:1 eval: (need, boundary) element masks from one
@@ -977,9 +988,10 @@ class BatchedOps:
         need_d, bm_d = _eval_progs(self.d, self.eclass)["need"](
             tgtD, khiD, kloD, validD, levD, *table.dev,
             *self._boundary_scalars(mt, mk, g, P))
-        _bump_fetch("eval_2to1")
-        # owned copies: callers fold masks in place (jax views are read-only)
-        return (np.array(need_d[:sw.n]), np.array(bm_d[:sw.n]))
+        need_d, bm_d = need_d[:sw.n], bm_d[:sw.n]
+        with self._eval_fetch("eval_2to1", sw.n, levD.shape[0]):
+            # owned copies: callers fold masks in place (jax views are read-only)
+            return np.array(need_d), np.array(bm_d)
 
     def eval_cache(self, sw: SweepHandle | None, cache: LeafTable | None,
                    mt, mk, g: int) -> np.ndarray:
@@ -1000,9 +1012,9 @@ class BatchedOps:
         tgtD, khiD, kloD, validD, _dualD, levD = sw.dev
         need_d = _eval_progs(self.d, self.eclass)["cache"](
             tgtD, khiD, kloD, validD, levD, *cache.dev,
-            *self._boundary_scalars(mt, mk, g, P))
-        _bump_fetch("eval_cache")
-        return np.array(need_d[:sw.n])
+            *self._boundary_scalars(mt, mk, g, P))[:sw.n]
+        with self._eval_fetch("eval_cache", sw.n, levD.shape[0]):
+            return np.array(need_d)
 
     def eval_route(self, sw: SweepHandle | None, mt, mk, g: int) -> RoutePairs:
         """Fused boundary routing: compact the (face, element) pairs whose
@@ -1033,11 +1045,12 @@ class BatchedOps:
         prog = "route" if which == "jnp" else "route_pallas"
         cnt, packed = _eval_progs(self.d, self.eclass)[prog](
             *sw.dev, mt_j, mkey.hi, mkey.lo, _rank_scalar(g))
-        _bump_fetch("eval_route")
-        c = int(cnt)
-        if c == 0:
-            return _empty_route()
-        arr = np.asarray(packed[:c])
+        telemetry.count("fetch.eval_route")
+        with fetch_span("eval_route"):
+            c = int(cnt)
+            if c == 0:
+                return _empty_route()
+            arr = np.asarray(packed[:c])
         khi = np.asarray(arr[:, 1], np.int64) & np.int64(0xFFFFFFFF)
         klo = np.asarray(arr[:, 2], np.int64) & np.int64(0xFFFFFFFF)
         key = (khi.astype(np.uint64) << np.uint64(32)) | klo.astype(np.uint64)

@@ -49,6 +49,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
+from . import telemetry
 from .errors import CommTimeoutError, WireFormatError, WireIntegrityError
 
 __all__ = [
@@ -392,29 +393,32 @@ class CommHandle:
         with none (the default), this is one blocking transport call."""
         if self._done:
             return self._result
-        deadline = self._deadline
-        if timeout is not None:
-            t = time.monotonic() + timeout
-            deadline = t if deadline is None else min(deadline, t)
-        if deadline is not None and self._poll is not None:
-            start = time.monotonic()
-            retries = 0
-            delay = 0.0005
-            while not self._poll():
-                now = time.monotonic()
-                if now >= deadline:
-                    raise CommTimeoutError(
-                        phase=self.phase, seq=self.seq,
-                        elapsed_s=now - start, retries=retries,
-                        pending=self._pending() if self._pending else None,
-                        detail=self._diagnose() if self._diagnose else None)
-                retries += 1
-                time.sleep(min(delay, deadline - now) * (0.5 + random.random()))
-                delay = min(delay * 2.0, 0.05)
-        self._result = self._complete()
-        self._complete = self._poll = None
-        self._done = True
-        return self._result
+        # the exposed wait on a collective in flight (immediate transports
+        # never get here)
+        with telemetry.span("comm.wait", phase=self.phase):
+            deadline = self._deadline
+            if timeout is not None:
+                t = time.monotonic() + timeout
+                deadline = t if deadline is None else min(deadline, t)
+            if deadline is not None and self._poll is not None:
+                start = time.monotonic()
+                retries = 0
+                delay = 0.0005
+                while not self._poll():
+                    now = time.monotonic()
+                    if now >= deadline:
+                        raise CommTimeoutError(
+                            phase=self.phase, seq=self.seq,
+                            elapsed_s=now - start, retries=retries,
+                            pending=self._pending() if self._pending else None,
+                            detail=self._diagnose() if self._diagnose else None)
+                    retries += 1
+                    time.sleep(min(delay, deadline - now) * (0.5 + random.random()))
+                    delay = min(delay * 2.0, 0.05)
+            self._result = self._complete()
+            self._complete = self._poll = None
+            self._done = True
+            return self._result
 
 
 # ----------------------------------------------------------------- the seam

@@ -44,8 +44,9 @@ from typing import Callable
 import numpy as np
 import jax.numpy as jnp
 
+from . import telemetry
 from . import u64 as u64m
-from .batch import BatchedOps, count_dispatch as batch_count_dispatch, get_batch_ops
+from .batch import BatchedOps, count_dispatch as batch_count_dispatch, fetch_span, get_batch_ops
 from .cmesh import Cmesh, wrap_i32
 from .comm import Comm, CommHandle, DistComm, LatencyComm, LocalComm, SimComm
 from .ops import ElementOps, get_ops
@@ -159,20 +160,21 @@ class Forest:
         stype = np.asarray(stype, np.int32)
         tree = np.asarray(tree, np.int32)
         ecs = self.eclasses
-        if len(ecs) == 1:
-            s = Simplex(jnp.asarray(anchor), jnp.asarray(level), jnp.asarray(stype))
-            keys = get_batch_ops(self.d, eclass=ecs[0]).morton_key_np(s)
-        else:
-            # mixed-class mesh: every tree's leaves encode with the tree's
-            # class — one batched key dispatch per class present
-            keys = np.zeros(len(level), np.uint64)
-            te = self.cmesh.tree_eclass[tree]
-            for ec in ecs:
-                m = te == ec
-                if m.any():
-                    s = Simplex(jnp.asarray(anchor[m]), jnp.asarray(level[m]),
-                                jnp.asarray(stype[m]))
-                    keys[m] = get_batch_ops(self.d, eclass=ec).morton_key_np(s)
+        with telemetry.span("keys.encode", elements=len(level)):
+            if len(ecs) == 1:
+                s = Simplex(jnp.asarray(anchor), jnp.asarray(level), jnp.asarray(stype))
+                keys = get_batch_ops(self.d, eclass=ecs[0]).morton_key_np(s)
+            else:
+                # mixed-class mesh: every tree's leaves encode with the tree's
+                # class — one batched key dispatch per class present
+                keys = np.zeros(len(level), np.uint64)
+                te = self.cmesh.tree_eclass[tree]
+                for ec in ecs:
+                    m = te == ec
+                    if m.any():
+                        s = Simplex(jnp.asarray(anchor[m]), jnp.asarray(level[m]),
+                                    jnp.asarray(stype[m]))
+                        keys[m] = get_batch_ops(self.d, eclass=ec).morton_key_np(s)
         return dataclasses.replace(
             self,
             anchor=anchor, level=level, stype=stype, tree=tree, keys=keys,
@@ -418,7 +420,8 @@ def _family_heads(f: Forest) -> np.ndarray:
         return heads
     s = f.simplices()
     parent, iloc = b.parent_and_local_index(s)
-    iloc = np.asarray(iloc)
+    with fetch_span("local_index"):
+        iloc = np.asarray(iloc)
     pkey = b.morton_key_np(parent)
     cand = np.nonzero((iloc[: n - nc + 1] == 0) & (f.level[: n - nc + 1] > 0))[0]
     ok = np.ones(len(cand), bool)
@@ -447,12 +450,13 @@ def adapt(f: Forest, callback: AdaptCallback, recursive: bool = False,
     elements) separately); sibling families never straddle classes because
     classes are unions of whole trees.
     """
-    groups = _class_groups(f)
-    if len(groups) > 1:
-        parts = [_adapt_impl(_subforest(f, idx), callback, recursive, max_passes)
-                 for _, idx in groups]
-        return _merge_class_groups(f, parts)
-    return _adapt_impl(f, callback, recursive, max_passes)
+    with telemetry.span("forest.adapt"):
+        groups = _class_groups(f)
+        if len(groups) > 1:
+            parts = [_adapt_impl(_subforest(f, idx), callback, recursive, max_passes)
+                     for _, idx in groups]
+            return _merge_class_groups(f, parts)
+        return _adapt_impl(f, callback, recursive, max_passes)
 
 
 def _adapt_impl(f: Forest, callback: AdaptCallback, recursive: bool,
@@ -462,81 +466,89 @@ def _adapt_impl(f: Forest, callback: AdaptCallback, recursive: bool,
     bops = f.bops
     refined_origin = np.zeros(f.num_local, bool)   # created by refine this call
     coarsened_origin = np.zeros(f.num_local, bool)
-    for _ in range(max_passes):
+    for pass_no in range(max_passes):
         n = f.num_local
         if n == 0:
             return f
-        s = f.simplices()
-        flags = np.asarray(callback(f.tree, s)).astype(np.int32)
-        assert flags.shape == (n,)
-        # never coarsen refine-children / never refine coarsen-parents
-        flags = np.where(refined_origin & (flags < 0), 0, flags)
-        flags = np.where(coarsened_origin & (flags > 0), 0, flags)
-        heads = _family_heads(f)
-        coarsen_head = heads.copy()
-        for k in range(nc):
-            idx = np.nonzero(heads)[0] + k
-            coarsen_head[np.nonzero(heads)[0]] &= flags[idx] < 0
-        # members of a coarsened family
-        member = np.zeros(n, bool)
-        hidx = np.nonzero(coarsen_head)[0]
-        for k in range(nc):
-            member[hidx + k] = True
-        refine = (flags > 0) & ~member & (f.level < o.L)
-        if not refine.any() and not coarsen_head.any():
-            break
-        keep = ~refine & ~member
+        with telemetry.span("adapt.pass", pass_no=pass_no, elements=n):
+            s = f.simplices()
+            with telemetry.span("adapt.callback"):
+                flags = np.asarray(callback(f.tree, s)).astype(np.int32)
+            assert flags.shape == (n,)
+            with telemetry.span("adapt.families"):
+                # never coarsen refine-children / never refine coarsen-parents
+                flags = np.where(refined_origin & (flags < 0), 0, flags)
+                flags = np.where(coarsened_origin & (flags > 0), 0, flags)
+                heads = _family_heads(f)
+                coarsen_head = heads.copy()
+                for k in range(nc):
+                    idx = np.nonzero(heads)[0] + k
+                    coarsen_head[np.nonzero(heads)[0]] &= flags[idx] < 0
+                # members of a coarsened family
+                member = np.zeros(n, bool)
+                hidx = np.nonzero(coarsen_head)[0]
+                for k in range(nc):
+                    member[hidx + k] = True
+                refine = (flags > 0) & ~member & (f.level < o.L)
+            if not refine.any() and not coarsen_head.any():
+                break
+            keep = ~refine & ~member
 
-        out_anchor, out_level, out_stype, out_tree = [], [], [], []
-        origin_r, origin_c = [], []
-        # sizes: keep->1, refine->nc, family head->1 (others 0)
-        counts = keep.astype(np.int64) + refine.astype(np.int64) * nc + coarsen_head.astype(np.int64)
-        total = int(counts.sum())
-        offs = np.zeros(n + 1, np.int64)
-        np.cumsum(counts, out=offs[1:])
-        A = np.zeros((total, o.d), np.int32)
-        L = np.zeros(total, np.int32)
-        B = np.zeros(total, np.int32)
-        T = np.zeros(total, np.int32)
-        OR = np.zeros(total, bool)
-        OC = np.zeros(total, bool)
-        # keeps
-        kidx = np.nonzero(keep)[0]
-        A[offs[kidx]] = f.anchor[kidx]
-        L[offs[kidx]] = f.level[kidx]
-        B[offs[kidx]] = f.stype[kidx]
-        T[offs[kidx]] = f.tree[kidx]
-        OR[offs[kidx]] = refined_origin[kidx]
-        OC[offs[kidx]] = coarsened_origin[kidx]
-        # refines
-        ridx = np.nonzero(refine)[0]
-        if len(ridx):
-            rs = Simplex(jnp.asarray(f.anchor[ridx]), jnp.asarray(f.level[ridx]),
-                         jnp.asarray(f.stype[ridx]))
-            kids = bops.children(rs)
-            ka = np.asarray(kids.anchor)      # (m, nc, d)
-            kl = np.asarray(kids.level)
-            kb = np.asarray(kids.stype)
-            pos = offs[ridx][:, None] + np.arange(nc)[None, :]
-            A[pos.reshape(-1)] = ka.reshape(-1, o.d)
-            L[pos.reshape(-1)] = kl.reshape(-1)
-            B[pos.reshape(-1)] = kb.reshape(-1)
-            T[pos.reshape(-1)] = np.repeat(f.tree[ridx], nc)
-            OR[pos.reshape(-1)] = True
-        # coarsens
-        if len(hidx):
-            hs = Simplex(jnp.asarray(f.anchor[hidx]), jnp.asarray(f.level[hidx]),
-                         jnp.asarray(f.stype[hidx]))
-            par = bops.parent(hs)
-            A[offs[hidx]] = np.asarray(par.anchor)
-            L[offs[hidx]] = np.asarray(par.level)
-            B[offs[hidx]] = np.asarray(par.stype)
-            T[offs[hidx]] = f.tree[hidx]
-            OC[offs[hidx]] = True
-        f = f.replace_elements(A, L, B, T)
-        refined_origin, coarsened_origin = OR, OC
-        if not recursive:
-            break
+            # sizes: keep->1, refine->nc, family head->1 (others 0)
+            counts = keep.astype(np.int64) + refine.astype(np.int64) * nc + coarsen_head.astype(np.int64)
+            total = int(counts.sum())
+            offs = np.zeros(n + 1, np.int64)
+            np.cumsum(counts, out=offs[1:])
+            A = np.zeros((total, o.d), np.int32)
+            L = np.zeros(total, np.int32)
+            B = np.zeros(total, np.int32)
+            T = np.zeros(total, np.int32)
+            OR = np.zeros(total, bool)
+            OC = np.zeros(total, bool)
+            # keeps
+            kidx = np.nonzero(keep)[0]
+            A[offs[kidx]] = f.anchor[kidx]
+            L[offs[kidx]] = f.level[kidx]
+            B[offs[kidx]] = f.stype[kidx]
+            T[offs[kidx]] = f.tree[kidx]
+            OR[offs[kidx]] = refined_origin[kidx]
+            OC[offs[kidx]] = coarsened_origin[kidx]
+            # refines
+            ridx = np.nonzero(refine)[0]
+            if len(ridx):
+                with telemetry.span("adapt.children", elements=len(ridx)):
+                    rs = Simplex(jnp.asarray(f.anchor[ridx]), jnp.asarray(f.level[ridx]),
+                                 jnp.asarray(f.stype[ridx]))
+                    kids = bops.children(rs)
+                    with fetch_span("children"):
+                        ka = np.asarray(kids.anchor)      # (m, nc, d)
+                        kl = np.asarray(kids.level)
+                        kb = np.asarray(kids.stype)
+                    pos = offs[ridx][:, None] + np.arange(nc)[None, :]
+                    A[pos.reshape(-1)] = ka.reshape(-1, o.d)
+                    L[pos.reshape(-1)] = kl.reshape(-1)
+                    B[pos.reshape(-1)] = kb.reshape(-1)
+                    T[pos.reshape(-1)] = np.repeat(f.tree[ridx], nc)
+                    OR[pos.reshape(-1)] = True
+            # coarsens
+            if len(hidx):
+                with telemetry.span("adapt.parents", elements=len(hidx)):
+                    hs = Simplex(jnp.asarray(f.anchor[hidx]), jnp.asarray(f.level[hidx]),
+                                 jnp.asarray(f.stype[hidx]))
+                    par = bops.parent(hs)
+                    with fetch_span("parent"):
+                        pa = np.asarray(par.anchor)
+                        pl = np.asarray(par.level)
+                        pb = np.asarray(par.stype)
+                    A[offs[hidx]] = pa
+                    L[offs[hidx]] = pl
+                    B[offs[hidx]] = pb
+                    T[offs[hidx]] = f.tree[hidx]
+                    OC[offs[hidx]] = True
+            f = f.replace_elements(A, L, B, T)
+            refined_origin, coarsened_origin = OR, OC
+            if not recursive:
+                break
     return f
 
 
@@ -858,12 +870,13 @@ def face_sweep_layer(f: Forest, tree_ids: np.ndarray, s: Simplex) -> FaceSweepLa
     nf = bops.nf
     sw = bops.face_sweep(s)
     # one host materialization per field; all later bookkeeping is numpy
-    anchor = np.asarray(sw.neighbor.anchor)
-    stype = np.asarray(sw.neighbor.stype)
-    level = np.asarray(s.level)
-    inside = np.asarray(sw.inside)
-    dual = np.asarray(sw.dual)
-    nkey = u64m.to_np(sw.key)
+    with fetch_span("face_sweep"):
+        anchor = np.asarray(sw.neighbor.anchor)
+        stype = np.asarray(sw.neighbor.stype)
+        level = np.asarray(s.level)
+        inside = np.asarray(sw.inside)
+        dual = np.asarray(sw.dual)
+        nkey = u64m.to_np(sw.key)
     tree_ids = np.asarray(tree_ids)
     n = level.shape[0]
     tgt = np.broadcast_to(tree_ids, (nf, n)).copy()
@@ -1025,23 +1038,24 @@ def balance(forests: list[Forest], comm: Comm, max_rounds: int = 64,
     in the same sorted order, and the per-rank results merge back into
     stored (tree, key) order.  Single-class meshes take the direct path —
     dispatch for dispatch the pre-eclass pipeline."""
-    _fire_hooks("balance:begin", forests, comm)
-    classes = _forest_classes(forests)
-    if len(classes) == 1:
-        out = _balance_impl(forests, comm, max_rounds=max_rounds,
-                            overlap=overlap, eclass=classes[0])
-    else:
-        parts: list[list] = [[] for _ in forests]
-        for ec in classes:
-            res = _balance_impl(_class_subforests(forests, ec), comm,
-                                max_rounds=max_rounds, overlap=overlap,
-                                eclass=ec)
-            for i, r in enumerate(res):
-                parts[i].append(r)
-        out = [_merge_class_groups(forests[i], ps)
-               for i, ps in enumerate(parts)]
-    _fire_hooks("balance:end", out, comm)
-    return out
+    with telemetry.span("forest.balance"):
+        _fire_hooks("balance:begin", forests, comm)
+        classes = _forest_classes(forests)
+        if len(classes) == 1:
+            out = _balance_impl(forests, comm, max_rounds=max_rounds,
+                                overlap=overlap, eclass=classes[0])
+        else:
+            parts: list[list] = [[] for _ in forests]
+            for ec in classes:
+                res = _balance_impl(_class_subforests(forests, ec), comm,
+                                    max_rounds=max_rounds, overlap=overlap,
+                                    eclass=ec)
+                for i, r in enumerate(res):
+                    parts[i].append(r)
+            out = [_merge_class_groups(forests[i], ps)
+                   for i, ps in enumerate(parts)]
+        _fire_hooks("balance:end", out, comm)
+        return out
 
 
 def _balance_impl(forests: list[Forest], comm: Comm, max_rounds: int = 64,
@@ -1190,11 +1204,12 @@ def _balance_impl(forests: list[Forest], comm: Comm, max_rounds: int = 64,
         def build_queries(i: int, sel: np.ndarray) -> dict:
             """Queries for an element subset (the per-round child layers):
             one fused sweep + the fused routing eval."""
-            h = sweep_handle(i, sel)
-            if h is None:
-                return {}
-            return route_to_dests(
-                i, bops.eval_route(h, mt, mk, comm.local_ranks[i]))
+            with telemetry.span("balance.route", elements=len(sel)):
+                h = sweep_handle(i, sel)
+                if h is None:
+                    return {}
+                return route_to_dests(
+                    i, bops.eval_route(h, mt, mk, comm.local_ranks[i]))
 
         def answer(i: int, src: int, buf: np.ndarray) -> set:
             """Register one rank's queries and answer them from the local
@@ -1260,56 +1275,61 @@ def _balance_impl(forests: list[Forest], comm: Comm, max_rounds: int = 64,
             The initial round passes the handles it already computed (they
             hid the marker allgather and built the first queries)."""
             if pre is None:
-                handles = [sweep_handle(i) for i in range(nloc)]
-                tables = upload_tables()
+                with telemetry.span("balance.sweep"):
+                    handles = [sweep_handle(i) for i in range(nloc)]
+                    tables = upload_tables()
             else:
                 handles, tables = pre
-            recv = pending.wait()
-            reply_rows, notif_bufs = [], []
-            for i in range(nloc):
-                g = comm.local_ranks[i]
-                row = [np.zeros(0, np.uint8)] * P
-                nbufs = []
-                for p in range(P):
-                    if p == g or recv[i][p] is None:
-                        continue
-                    nbuf, qbuf = recv[i][p]
-                    if len(nbuf):
-                        nbufs.append(nbuf)
-                    if len(qbuf):
-                        row[p] = _pack_triples(answer(i, p, qbuf), eclass)
-                reply_rows.append(row)
-                notif_bufs.append(nbufs)
-            hr = post(comm.ialltoallv(reply_rows))
-            # everything below merge 1 overlaps the reply flight: fold the
-            # received notifications, then the interior (local-only) eval
-            for i in range(nloc):
-                for nbuf in notif_bufs[i]:
-                    t_, k_, l_ = unpack_wire(nbuf)
-                    cache_entries[i].update(
-                        zip(t_.tolist(), k_.tolist(), l_.tolist()))
-            needs = []
-            for i in range(nloc):
-                if handles[i] is None:
-                    needs.append(np.zeros(forests[i].num_local, bool))
-                else:
-                    nd, _bm = bops.eval_2to1(
-                        handles[i], tables[i], mt, mk, comm.local_ranks[i])
-                    needs.append(nd)
-            rrecv = hr.wait()
-            for i in range(nloc):
-                g = comm.local_ranks[i]
-                for p in range(P):
-                    buf = rrecv[i][p]
-                    if p == g or buf is None or not len(buf):
-                        continue
-                    t_, k_, l_ = unpack_wire(buf)
-                    cache_entries[i].update(zip(t_.tolist(), k_.tolist(), l_.tolist()))
-                recompile_cache(i)
-            for i in range(nloc):
-                if handles[i] is not None and cache_tables[i] is not None:
-                    needs[i] |= bops.eval_cache(
-                        handles[i], cache_tables[i], mt, mk, comm.local_ranks[i])
+            with telemetry.span("balance.answer"):
+                recv = pending.wait()
+                reply_rows, notif_bufs = [], []
+                for i in range(nloc):
+                    g = comm.local_ranks[i]
+                    row = [np.zeros(0, np.uint8)] * P
+                    nbufs = []
+                    for p in range(P):
+                        if p == g or recv[i][p] is None:
+                            continue
+                        nbuf, qbuf = recv[i][p]
+                        if len(nbuf):
+                            nbufs.append(nbuf)
+                        if len(qbuf):
+                            row[p] = _pack_triples(answer(i, p, qbuf), eclass)
+                    reply_rows.append(row)
+                    notif_bufs.append(nbufs)
+                hr = post(comm.ialltoallv(reply_rows))
+                # everything below merge 1 overlaps the reply flight: fold the
+                # received notifications, then the interior (local-only) eval
+                for i in range(nloc):
+                    for nbuf in notif_bufs[i]:
+                        t_, k_, l_ = unpack_wire(nbuf)
+                        cache_entries[i].update(
+                            zip(t_.tolist(), k_.tolist(), l_.tolist()))
+            with telemetry.span("balance.eval"):
+                needs = []
+                for i in range(nloc):
+                    if handles[i] is None:
+                        needs.append(np.zeros(forests[i].num_local, bool))
+                    else:
+                        nd, _bm = bops.eval_2to1(
+                            handles[i], tables[i], mt, mk, comm.local_ranks[i])
+                        needs.append(nd)
+            with telemetry.span("balance.merge"):
+                rrecv = hr.wait()
+                for i in range(nloc):
+                    g = comm.local_ranks[i]
+                    for p in range(P):
+                        buf = rrecv[i][p]
+                        if p == g or buf is None or not len(buf):
+                            continue
+                        t_, k_, l_ = unpack_wire(buf)
+                        cache_entries[i].update(zip(t_.tolist(), k_.tolist(), l_.tolist()))
+                    recompile_cache(i)
+            with telemetry.span("balance.eval_cache"):
+                for i in range(nloc):
+                    if handles[i] is not None and cache_tables[i] is not None:
+                        needs[i] |= bops.eval_cache(
+                            handles[i], cache_tables[i], mt, mk, comm.local_ranks[i])
             return needs
 
         def refine_and_build(needs: list[np.ndarray]):
@@ -1318,69 +1338,83 @@ def _balance_impl(forests: list[Forest], comm: Comm, max_rounds: int = 64,
             on the wire)."""
             new_dests: list[dict] = [{} for _ in range(nloc)]
             new_notifs: list[dict] = [{} for _ in range(nloc)]
-            for i in range(nloc):
-                nd = needs[i]
-                if not nd.any():
-                    continue
-                f = forests[i]
-                # the changed boundary layer: all children created this round
-                ev = np.nonzero(nd)[0]
-                ct = np.repeat(f.tree[ev], nc)
-                cl = np.repeat(f.level[ev] + 1, nc)
-                cspan = np.uint64(1) << (
-                    np.uint64(d) * (L - 1 - f.level[ev]).astype(np.uint64))
-                ck = (f.keys[ev][:, None] + np.arange(nc, dtype=np.uint64)[None, :]
-                      * cspan[:, None]).reshape(-1)
-                flags = nd.astype(np.int32)
-                f2 = adapt(f, lambda tree, elems, fl=flags: fl, recursive=False)
-                forests[i] = f2
-                # new children re-enter the protocol: locate them (one
-                # searchsorted per tree run of the stored (tree, key) order) ...
-                sel = np.empty(len(ct), np.int64)
-                for t in np.unique(ct):
-                    m = ct == t
-                    a, b = np.searchsorted(f2.tree, [t, t + 1])
-                    sel[m] = a + np.searchsorted(f2.keys[a:b], ck[m])
-                new_dests[i] = build_queries(i, np.sort(sel))
-                # ... and are pushed to every rank whose registered query
-                # interval they fall into (and whom they could make refine)
-                reg = registries[i]
-                if reg:
-                    exps_by_tree: dict[int, list] = {}
-                    for (t, se) in reg:
-                        exps_by_tree.setdefault(t, []).append(se)
-                    for (t, k, l) in zip(ct.tolist(), ck.tolist(), cl.tolist()):
-                        for se in exps_by_tree.get(t, ()):
-                            ent = reg[(t, se)].get((k >> se) << se)
-                            if ent is not None and l > ent[0] + 1:
-                                for r in ent[1]:
-                                    new_notifs[i].setdefault(r, set()).add((t, k, l))
+            with telemetry.span("balance.refine"):
+                for i in range(nloc):
+                    nd = needs[i]
+                    if not nd.any():
+                        continue
+                    f = forests[i]
+                    # the changed boundary layer: all children created this round
+                    ev = np.nonzero(nd)[0]
+                    ct = np.repeat(f.tree[ev], nc)
+                    cl = np.repeat(f.level[ev] + 1, nc)
+                    cspan = np.uint64(1) << (
+                        np.uint64(d) * (L - 1 - f.level[ev]).astype(np.uint64))
+                    ck = (f.keys[ev][:, None] + np.arange(nc, dtype=np.uint64)[None, :]
+                          * cspan[:, None]).reshape(-1)
+                    flags = nd.astype(np.int32)
+                    f2 = adapt(f, lambda tree, elems, fl=flags: fl, recursive=False)
+                    forests[i] = f2
+                    # new children re-enter the protocol: locate them (one
+                    # searchsorted per tree run of the stored (tree, key) order) ...
+                    sel = np.empty(len(ct), np.int64)
+                    for t in np.unique(ct):
+                        m = ct == t
+                        a, b = np.searchsorted(f2.tree, [t, t + 1])
+                        sel[m] = a + np.searchsorted(f2.keys[a:b], ck[m])
+                    new_dests[i] = build_queries(i, np.sort(sel))
+                    # ... and are pushed to every rank whose registered query
+                    # interval they fall into (and whom they could make refine)
+                    reg = registries[i]
+                    if reg:
+                        exps_by_tree: dict[int, list] = {}
+                        for (t, se) in reg:
+                            exps_by_tree.setdefault(t, []).append(se)
+                        for (t, k, l) in zip(ct.tolist(), ck.tolist(), cl.tolist()):
+                            for se in exps_by_tree.get(t, ()):
+                                ent = reg[(t, se)].get((k >> se) << se)
+                                if ent is not None and l > ent[0] + 1:
+                                    for r in ent[1]:
+                                        new_notifs[i].setdefault(r, set()).add((t, k, l))
             return new_dests, new_notifs
 
-        # initial round: the device sweeps + table uploads dispatch while
-        # the marker allgather flies, then double-duty as both the first
-        # query builder and the first eval layer; the initial halo (every
-        # element registers + queries its remote intervals) is itself
-        # posted nonblocking
-        handles0 = [sweep_handle(i) for i in range(nloc)]
-        tables0 = upload_tables()
-        mt, mk = _markers_from_pairs(K, P, h_mk.wait())
-        pending = post(post_exchange(
-            [route_to_dests(i, bops.eval_route(handles0[i], mt, mk,
-                                               comm.local_ranks[i]))
-             if handles0[i] is not None else {}
-             for i in range(nloc)], None))
-        needs = eval_round(pending, (handles0, tables0))
-        for _ in range(max_rounds):
-            # post the convergence vote, then refine + build the next
-            # round's messages while it is on the wire (a no-op when the
-            # vote comes back all-clear: nothing was dirty anywhere)
-            h_conv = post(comm.iallgather([int(nd.any()) for nd in needs]))
-            new_dests, new_notifs = refine_and_build(needs)
-            if not any(h_conv.wait()):
-                return forests
-            pending = post(post_exchange(new_dests, new_notifs))
-            needs = eval_round(pending)
+        # one eval round each, at most max_rounds refining them; round r
+        # evaluates, votes on convergence, refines and posts round r + 1's
+        # messages
+        for rnd in range(max_rounds + 1):
+            with telemetry.span("balance.round", round=rnd,
+                                elements=sum(f.num_local for f in forests)):
+                if rnd == 0:
+                    # initial round: the device sweeps + table uploads
+                    # dispatch while the marker allgather flies, then
+                    # double-duty as both the first query builder and the
+                    # first eval layer; the initial halo (every element
+                    # registers + queries its remote intervals) is itself
+                    # posted nonblocking
+                    with telemetry.span("balance.sweep"):
+                        handles0 = [sweep_handle(i) for i in range(nloc)]
+                        tables0 = upload_tables()
+                    with telemetry.span("balance.route"):
+                        mt, mk = _markers_from_pairs(K, P, h_mk.wait())
+                        pending = post(post_exchange(
+                            [route_to_dests(i, bops.eval_route(
+                                handles0[i], mt, mk, comm.local_ranks[i]))
+                             if handles0[i] is not None else {}
+                             for i in range(nloc)], None))
+                    needs = eval_round(pending, (handles0, tables0))
+                else:
+                    needs = eval_round(pending)
+                if rnd == max_rounds:
+                    break
+                # post the convergence vote, then refine + build the next
+                # round's messages while it is on the wire (a no-op when the
+                # vote comes back all-clear: nothing was dirty anywhere)
+                h_conv = post(comm.iallgather([int(nd.any()) for nd in needs]))
+                new_dests, new_notifs = refine_and_build(needs)
+                if not any(h_conv.wait()):
+                    return forests
+                with telemetry.span("balance.route"):
+                    pending = post(post_exchange(new_dests, new_notifs))
         # budget exhausted: the last eval (which completed the last round's
         # exchange) decides converged-on-last-round vs genuinely dirty
         counts = comm.allgather([int(nd.sum()) for nd in needs])
@@ -1491,8 +1525,9 @@ def _ghost_from_candidates(d: int, cmesh: Cmesh | None, cand: set) -> dict:
         m = te == ec
         gs = get_batch_ops(d, eclass=int(ec)).decode(
             u64m.from_int(keys[m]), jnp.asarray(levels[m]))
-        anchors[m] = np.asarray(gs.anchor)
-        stypes[m] = np.asarray(gs.stype)
+        with fetch_span("decode"):
+            anchors[m] = np.asarray(gs.anchor)
+            stypes[m] = np.asarray(gs.stype)
     return {"anchor": anchors, "level": levels,
             "stype": stypes, "tree": trees, "owner": owners}
 
@@ -1534,17 +1569,19 @@ def ghost(forests: list[Forest], comm: Comm, overlap: bool = True) -> list[dict]
     layers are bit-identical across overlap modes."""
     d = forests[0].d
     cm = forests[0].cmesh
-    classes = _forest_classes(forests)
-    if len(classes) == 1:
-        cands = _ghost_impl(forests, comm, overlap, classes[0])
-    else:
-        cands = [set() for _ in forests]
-        for ec in classes:
-            res = _ghost_impl(_class_subforests(forests, ec), comm,
-                              overlap, ec)
-            for i, c in enumerate(res):
-                cands[i] |= c
-    return [_ghost_from_candidates(d, cm, c) for c in cands]
+    with telemetry.span("forest.ghost"):
+        classes = _forest_classes(forests)
+        if len(classes) == 1:
+            cands = _ghost_impl(forests, comm, overlap, classes[0])
+        else:
+            cands = [set() for _ in forests]
+            for ec in classes:
+                res = _ghost_impl(_class_subforests(forests, ec), comm,
+                                  overlap, ec)
+                for i, c in enumerate(res):
+                    cands[i] |= c
+        with telemetry.span("ghost.assemble"):
+            return [_ghost_from_candidates(d, cm, c) for c in cands]
 
 
 def _ghost_impl(forests: list[Forest], comm: Comm, overlap: bool,
@@ -1569,130 +1606,136 @@ def _ghost_impl(forests: list[Forest], comm: Comm, overlap: bool,
         return h if overlap else CommHandle.ready(h.wait())
 
     with comm.phase("ghost"):
-        # markers fly while the device routing sweeps dispatch
-        K = forests[0].num_trees
-        h_mk = post(comm.iallgather(_marker_pairs(forests)))
-        handles = [_resident_sweep(f, bops) for f in forests]
-        mt, mk = _markers_from_pairs(K, P, h_mk.wait())
+        with telemetry.span("ghost.route"):
+            # markers fly while the device routing sweeps dispatch
+            K = forests[0].num_trees
+            h_mk = post(comm.iallgather(_marker_pairs(forests)))
+            handles = [_resident_sweep(f, bops) for f in forests]
+            mt, mk = _markers_from_pairs(K, P, h_mk.wait())
 
-        # ---- route queries: the fused eval compacts the remote-reaching
-        # (face, element) pairs; the host only packs wire quads from them
-        send = []
-        for i in range(nloc):
-            g = comm.local_ranks[i]
-            dest: dict[int, set] = {}
-            if handles[i] is not None:
-                rp = bops.eval_route(handles[i], mt, mk, g)
-                for j in range(len(rp.tree)):
-                    q = (int(rp.tree[j]), int(rp.key[j]), int(rp.level[j]),
-                         int(rp.dual[j]))
-                    for r in range(int(rp.first[j]), int(rp.last[j]) + 1):
-                        if r != g:
-                            dest.setdefault(r, set()).add(q)
-            row = []
-            for q in range(P):
-                qs = sorted(dest.get(q, ()))
-                row.append(pack_wire(
-                    np.array([x[0] for x in qs], np.int32),
-                    np.array([x[1] for x in qs], np.uint64),
-                    np.array([x[2] for x in qs], np.int32),
-                    extra=np.array([x[3] for x in qs], np.int32),
-                    eclass=eclass,
-                ) if qs else np.zeros(0, np.uint8))
-            send.append(row)
-        h_q = post(comm.ialltoallv(send))
-        # answering-side prep hides the query flight: per-tree offsets into
-        # each rank's sorted leaf arrays replace per-query searchsorted
-        tree_offs = [np.searchsorted(f.tree, np.arange(K + 1))
-                     for f in forests]
-        recv = h_q.wait()
+            # ---- route queries: the fused eval compacts the remote-reaching
+            # (face, element) pairs; the host only packs wire quads from them
+            send = []
+            for i in range(nloc):
+                g = comm.local_ranks[i]
+                dest: dict[int, set] = {}
+                if handles[i] is not None:
+                    rp = bops.eval_route(handles[i], mt, mk, g)
+                    for j in range(len(rp.tree)):
+                        q = (int(rp.tree[j]), int(rp.key[j]), int(rp.level[j]),
+                             int(rp.dual[j]))
+                        for r in range(int(rp.first[j]), int(rp.last[j]) + 1):
+                            if r != g:
+                                dest.setdefault(r, set()).add(q)
+                row = []
+                for q in range(P):
+                    qs = sorted(dest.get(q, ()))
+                    row.append(pack_wire(
+                        np.array([x[0] for x in qs], np.int32),
+                        np.array([x[1] for x in qs], np.uint64),
+                        np.array([x[2] for x in qs], np.int32),
+                        extra=np.array([x[3] for x in qs], np.int32),
+                        eclass=eclass,
+                    ) if qs else np.zeros(0, np.uint8))
+                send.append(row)
+            h_q = post(comm.ialltoallv(send))
+        with telemetry.span("ghost.answer"):
+            # answering-side prep hides the query flight: per-tree offsets into
+            # each rank's sorted leaf arrays replace per-query searchsorted
+            tree_offs = [np.searchsorted(f.tree, np.arange(K + 1))
+                         for f in forests]
+            recv = h_q.wait()
 
-        # ---- answer from the local sorted arrays
-        reply_rows = []
-        for i, f in enumerate(forests):
-            g = comm.local_ranks[i]
-            row = [np.zeros(0, np.uint8)] * P
-            entries = []  # (src, tree, k0, level, dual)
-            for p in range(P):
-                buf = recv[i][p]
-                if p == g or buf is None or not len(buf):
-                    continue
-                qt, qk, ql, qd = unpack_wire(buf, with_extra=True)
-                entries.extend(
-                    (p, t, k, l, du) for t, k, l, du in
-                    zip(qt.tolist(), qk.tolist(), ql.tolist(), qd.tolist()))
-            replies: dict[int, set] = {}
-            if entries and f.num_local:
-                offs = tree_offs[i]
-                pend = []       # (entry idx, local leaf idx) same-or-finer
-                pred_hits = []  # (entry idx, local leaf idx) coarser containing
-                for ei, (p, t, k0, l, du) in enumerate(entries):
-                    t0 = int(offs[t])
-                    keys_t = f.keys[t0:int(offs[t + 1])]
-                    span_q = np.uint64(1) << np.uint64(d * (L - l))
-                    a = int(np.searchsorted(keys_t, np.uint64(k0)))
-                    b = int(np.searchsorted(keys_t, np.uint64(k0) + span_q))
-                    if b > a:
-                        pend.extend((ei, t0 + j) for j in range(a, b))
-                    elif a > 0:
-                        # coarser containing leaf: dyadic nesting makes the
-                        # interval globally empty, and the leaf lives on the
-                        # owner rank of k0 — answer only there (owner via one
-                        # numpy compare-sum on the marker table, no dispatch)
-                        own = max(int(((mt < t) | ((mt == t) & (
-                            mk <= np.uint64(k0)))).sum()) - 1, 0)
-                        jj = t0 + a - 1
-                        span_p = np.uint64(1) << np.uint64(d * (L - int(f.level[jj])))
-                        if own == g and np.uint64(f.keys[jj]) + span_p > np.uint64(k0):
-                            pred_hits.append((ei, jj))
-                if pend:
-                    # same-or-finer leaves must TOUCH the shared face: a
-                    # whole facet's worth of their corners on the plane of
-                    # the neighbor element's dual facet (the neighbor is
-                    # decoded from the query key)
-                    eis = sorted({ei for ei, _ in pend})
-                    emap = {ei: k for k, ei in enumerate(eis)}
-                    ent_k = np.array([entries[ei][2] for ei in eis], np.uint64)
-                    ent_l = np.array([entries[ei][3] for ei in eis], np.int32)
-                    nbs = bops.decode(u64m.from_int(ent_k), jnp.asarray(ent_l))
-                    nbc = np.asarray(o.coordinates(nbs), np.int64)
-                    js = sorted({j for _, j in pend})
-                    jmap = {j: k for k, j in enumerate(js)}
-                    jarr = np.asarray(js, np.int64)
-                    cs = Simplex(jnp.asarray(f.anchor[jarr]),
-                                 jnp.asarray(f.level[jarr]),
-                                 jnp.asarray(f.stype[jarr]))
-                    ccoords = np.asarray(o.coordinates(cs), np.int64)
-                    planes: dict[int, tuple] = {}
-                    for ei, j in pend:
-                        if ei not in planes:
-                            planes[ei] = face_plane(
-                                nbc[emap[ei]][fci[int(entries[ei][4])][:d]])
-                        nrm, rhs = planes[ei]
-                        if (ccoords[jmap[j]] @ nrm == rhs).sum() == cpf:
-                            replies.setdefault(entries[ei][0], set()).add(
-                                (int(f.tree[j]), int(f.keys[j]), int(f.level[j])))
-                for ei, j in pred_hits:
-                    replies.setdefault(entries[ei][0], set()).add(
-                        (int(f.tree[j]), int(f.keys[j]), int(f.level[j])))
-            for p, rs in replies.items():
-                row[p] = _pack_triples(rs, eclass)
-            reply_rows.append(row)
-        rrecv = post(comm.ialltoallv(reply_rows)).wait()
+            # ---- answer from the local sorted arrays
+            reply_rows = []
+            for i, f in enumerate(forests):
+                g = comm.local_ranks[i]
+                row = [np.zeros(0, np.uint8)] * P
+                entries = []  # (src, tree, k0, level, dual)
+                for p in range(P):
+                    buf = recv[i][p]
+                    if p == g or buf is None or not len(buf):
+                        continue
+                    qt, qk, ql, qd = unpack_wire(buf, with_extra=True)
+                    entries.extend(
+                        (p, t, k, l, du) for t, k, l, du in
+                        zip(qt.tolist(), qk.tolist(), ql.tolist(), qd.tolist()))
+                replies: dict[int, set] = {}
+                if entries and f.num_local:
+                    offs = tree_offs[i]
+                    pend = []       # (entry idx, local leaf idx) same-or-finer
+                    pred_hits = []  # (entry idx, local leaf idx) coarser containing
+                    for ei, (p, t, k0, l, du) in enumerate(entries):
+                        t0 = int(offs[t])
+                        keys_t = f.keys[t0:int(offs[t + 1])]
+                        span_q = np.uint64(1) << np.uint64(d * (L - l))
+                        a = int(np.searchsorted(keys_t, np.uint64(k0)))
+                        b = int(np.searchsorted(keys_t, np.uint64(k0) + span_q))
+                        if b > a:
+                            pend.extend((ei, t0 + j) for j in range(a, b))
+                        elif a > 0:
+                            # coarser containing leaf: dyadic nesting makes the
+                            # interval globally empty, and the leaf lives on the
+                            # owner rank of k0 — answer only there (owner via one
+                            # numpy compare-sum on the marker table, no dispatch)
+                            own = max(int(((mt < t) | ((mt == t) & (
+                                mk <= np.uint64(k0)))).sum()) - 1, 0)
+                            jj = t0 + a - 1
+                            span_p = np.uint64(1) << np.uint64(d * (L - int(f.level[jj])))
+                            if own == g and np.uint64(f.keys[jj]) + span_p > np.uint64(k0):
+                                pred_hits.append((ei, jj))
+                    if pend:
+                        # same-or-finer leaves must TOUCH the shared face: a
+                        # whole facet's worth of their corners on the plane of
+                        # the neighbor element's dual facet (the neighbor is
+                        # decoded from the query key)
+                        eis = sorted({ei for ei, _ in pend})
+                        emap = {ei: k for k, ei in enumerate(eis)}
+                        ent_k = np.array([entries[ei][2] for ei in eis], np.uint64)
+                        ent_l = np.array([entries[ei][3] for ei in eis], np.int32)
+                        nbs = bops.decode(u64m.from_int(ent_k), jnp.asarray(ent_l))
+                        with fetch_span("ghost_coords"):
+                            nbc = np.asarray(o.coordinates(nbs), np.int64)
+                        js = sorted({j for _, j in pend})
+                        jmap = {j: k for k, j in enumerate(js)}
+                        jarr = np.asarray(js, np.int64)
+                        cs = Simplex(jnp.asarray(f.anchor[jarr]),
+                                     jnp.asarray(f.level[jarr]),
+                                     jnp.asarray(f.stype[jarr]))
+                        with fetch_span("ghost_coords"):
+                            ccoords = np.asarray(o.coordinates(cs), np.int64)
+                        planes: dict[int, tuple] = {}
+                        for ei, j in pend:
+                            if ei not in planes:
+                                planes[ei] = face_plane(
+                                    nbc[emap[ei]][fci[int(entries[ei][4])][:d]])
+                            nrm, rhs = planes[ei]
+                            if (ccoords[jmap[j]] @ nrm == rhs).sum() == cpf:
+                                replies.setdefault(entries[ei][0], set()).add(
+                                    (int(f.tree[j]), int(f.keys[j]), int(f.level[j])))
+                    for ei, j in pred_hits:
+                        replies.setdefault(entries[ei][0], set()).add(
+                            (int(f.tree[j]), int(f.keys[j]), int(f.level[j])))
+                for p, rs in replies.items():
+                    row[p] = _pack_triples(rs, eclass)
+                reply_rows.append(row)
+            h_r = post(comm.ialltoallv(reply_rows))
+        with telemetry.span("ghost.assemble"):
+            rrecv = h_r.wait()
 
-        # ---- collect candidates: replies from rank p are leaves owned by p
-        out = []
-        for i, f in enumerate(forests):
-            g = comm.local_ranks[i]
-            cand: set = set()
-            for p in range(P):
-                buf = rrecv[i][p]
-                if p == g or buf is None or not len(buf):
-                    continue
-                t_, k_, l_ = unpack_wire(buf)
-                cand.update((t, k, l, p) for t, k, l in
-                            zip(t_.tolist(), k_.tolist(), l_.tolist()))
-            out.append(cand)
+            # ---- collect candidates: replies from rank p are leaves owned by p
+            out = []
+            for i, f in enumerate(forests):
+                g = comm.local_ranks[i]
+                cand: set = set()
+                for p in range(P):
+                    buf = rrecv[i][p]
+                    if p == g or buf is None or not len(buf):
+                        continue
+                    t_, k_, l_ = unpack_wire(buf)
+                    cand.update((t, k, l, p) for t, k, l in
+                                zip(t_.tolist(), k_.tolist(), l_.tolist()))
+                out.append(cand)
         return out
 
 

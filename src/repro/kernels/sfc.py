@@ -576,6 +576,7 @@ def morton_key_kernel(d: int, *arrays, block: int = DEFAULT_BLOCK, interpret: bo
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.uint32)] * 2,
         interpret=interpret,
+        name="morton_key",
     )(*arrays)
 
 
@@ -591,6 +592,7 @@ def decode_kernel(d: int, hi, lo, level, block: int = DEFAULT_BLOCK, interpret: 
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * (d + 1),
         interpret=interpret,
+        name="decode",
     )(hi, lo, level)
 
 
@@ -607,6 +609,7 @@ def face_neighbor_kernel(d: int, *arrays, block: int = DEFAULT_BLOCK, interpret:
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * (d + 2),
         interpret=interpret,
+        name="face_neighbor",
     )(*arrays)
 
 
@@ -629,6 +632,7 @@ def face_sweep_kernel(d: int, *arrays, block: int = DEFAULT_BLOCK, interpret: bo
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="face_sweep",
     )(*arrays)
 
 
@@ -646,6 +650,7 @@ def parent_kernel(d: int, *arrays, block: int = DEFAULT_BLOCK, interpret: bool =
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * (d + 2),
         interpret=interpret,
+        name="parent",
     )(*arrays)
 
 
@@ -664,6 +669,7 @@ def children_kernel(d: int, *arrays, block: int = DEFAULT_BLOCK, interpret: bool
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name="children",
     )(*arrays)
 
 
@@ -680,6 +686,7 @@ def inside_root_kernel(d: int, *arrays, block: int = DEFAULT_BLOCK, interpret: b
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)],
         interpret=interpret,
+        name="inside_root",
     )(*arrays)
 
 
@@ -698,6 +705,7 @@ def tree_transform_kernel(d: int, M, c, tmap, *arrays,
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * (d + 1),
         interpret=interpret,
+        name="tree_transform",
     )(*arrays)
 
 
@@ -717,6 +725,7 @@ def owner_rank_kernel(t, hi, lo, mt, mhi, mlo,
         out_specs=[spec],
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)],
         interpret=interpret,
+        name="owner_rank",
     )(t, hi, lo, mt, mhi, mlo)[0]
 
 
@@ -740,6 +749,7 @@ def eval_route_kernel(d: int, t, hi, lo, lvl, mt, mhi, mlo,
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.uint32)] * 2
         + [jax.ShapeDtypeStruct((n,), jnp.int32)] * 2,
         interpret=interpret,
+        name="eval_route",
     )(t, hi, lo, lvl, mt, mhi, mlo)
 
 
@@ -756,4 +766,5 @@ def successor_kernel(d: int, *arrays, block: int = DEFAULT_BLOCK, interpret: boo
         out_specs=out_specs,
         out_shape=[jax.ShapeDtypeStruct((n,), jnp.int32)] * (d + 1),
         interpret=interpret,
+        name="successor",
     )(*arrays)

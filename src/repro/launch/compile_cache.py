@@ -11,7 +11,8 @@ power-of-two bucket, so every process that drives the pipeline on a chip
 
 `compile_counter()` counts the backend compiles of the process (a hit in
 the persistent cache still counts one, and also counts as a hit), so a
-caller can print how many programs a phase compiled.
+caller can print how many programs a phase compiled, and the seconds spent
+tracing and lowering programs, in all and per program.
 """
 
 from __future__ import annotations
@@ -44,13 +45,49 @@ def enable_compile_cache() -> str:
     return path
 
 
-_counts = {"compiles": 0, "compile_s": 0.0, "cache_hits": 0}
+_counts = {"compiles": 0, "compile_s": 0.0, "cache_hits": 0,
+           "lower_s": 0.0, "lower_s_by_program": {}}
 _listening = False
+# Set-up lowering: tracing a program to a jaxpr, then converting the jaxpr
+# to an MLIR module.  Both happen before the persistent cache is asked, in
+# every process, so a warm cache does not save them.
+_LOWER_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration")
+# The lowering intervals counted so far that no later one has enclosed:
+# tracing a program traces the programs it calls, and their events end
+# first, inside the caller's.
+_lowered: list = []
+
+
+def _on_lower(event, start, end, fun_name="?", **_kw):
+    if event not in _LOWER_EVENTS:
+        return
+    inner = 0.0
+    while _lowered and _lowered[-1][0] >= start and _lowered[-1][1] <= end:
+        s, e = _lowered.pop()
+        inner += e - s
+    own = (end - start) - inner
+    # the MLIR event names the module `jit(<name>)`, the trace event `<name>`
+    name = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+    by = _counts["lower_s_by_program"]
+    by[name] = by.get(name, 0.0) + own
+    _counts["lower_s"] += own
+    _lowered.append((start, end))
+    del _lowered[:-64]
 
 
 def compile_counter() -> dict:
-    """The live {compiles, compile_s, cache_hits} counters of this process
-    (installed on first call; read differences around a region)."""
+    """The live counters of this process (installed on first call; read
+    differences around a region):
+
+      compiles, compile_s   backend compiles and their seconds
+      cache_hits            persistent-cache hits
+      lower_s               seconds of tracing and lowering to MLIR, each
+                            second counted once where programs nest
+      lower_s_by_program    the same by program (function) name, each
+                            program's own seconds, without those of the
+                            programs it calls; the values add up to lower_s
+    """
     global _listening
     if not _listening:
         import jax.monitoring as mon
@@ -66,5 +103,6 @@ def compile_counter() -> dict:
 
         mon.register_event_duration_secs_listener(on_duration)
         mon.register_event_listener(on_event)
+        mon.register_event_time_span_listener(_on_lower)
         _listening = True
     return _counts
