@@ -312,6 +312,31 @@ def owner_rank_lex(t, hi, lo, mt, mhi, mlo):
 _owner_rank_jnp = jax.jit(owner_rank_lex)
 
 
+def lex_lower_bound(lt, lhi, llo, qt, qhi, qlo):
+    """For each query (qt, qhi, qlo), the index of the first row of the
+    lex-sorted table (lt, lhi, llo) that is lex->= it, or the table's
+    length when none is: `searchsorted(side="left")` over (tree, key), with
+    the uint64 keys carried as (hi, lo) uint32 words.  Queries are 1-D.
+
+    One merged sort answers every query at once: the queries go first and
+    the sort is stable, so a query lands before any table row equal to it,
+    and the table rows ahead of it in merged order are exactly its lower
+    bound.  A second sort, on each merged row's origin, brings those counts
+    back to query order.  A TPU sorts a batch far faster than it gathers
+    element by element, which a binary search does log2(len(lt)) times per
+    query."""
+    q = qt.shape[0]
+    origin = jnp.arange(q + lt.shape[0], dtype=jnp.int32)
+    *_, origin = jax.lax.sort(
+        (jnp.concatenate([qt, lt]), jnp.concatenate([qhi, lhi]),
+         jnp.concatenate([qlo, llo]), origin),
+        num_keys=3, is_stable=True)
+    is_row = (origin >= q).astype(jnp.int32)
+    rows_before = jnp.cumsum(is_row) - is_row
+    _, rows_before = jax.lax.sort((origin, rows_before), num_keys=1)
+    return rows_before[:q]
+
+
 # ------------------------------------------------------- device-resident eval
 # The fused Balance/Ghost eval stage.  A round's evaluation is three device
 # programs over ONE resident face sweep — need-mask vs the local leaf table,
@@ -343,8 +368,9 @@ class LeafTable(NamedTuple):
     """A lex-sorted (tree, key, level) leaf table — the local leaves or the
     remote-leaf cache — uploaded once per Balance round.  `host` feeds the
     reference oracle; `dev` is padded to a power of two with lex-+inf
-    sentinels (tree = int32 max, level = -1) so the device binary search
-    never counts padding."""
+    sentinels (tree = int32 max, level = -1), which sort after every leaf
+    in the device's merged sort (`lex_lower_bound`) and never count as a
+    finer leaf."""
 
     n: int
     host: tuple | None
@@ -417,22 +443,6 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
             (t1 == t2) & ((h1 < h2) | ((h1 == h2) & (l1 < l2)))
         )
 
-    def lower_bound(lt, lhi, llo, qt, qhi, qlo):
-        # Uniform binary search over the pow2-padded lex table: first index
-        # whose (tree, key) is lex->= the query.  The residual compare after
-        # the loop settles the all-entries-less case (pos would stick at
-        # m - 1 without it).
-        m = lt.shape[0]
-        pos = jnp.zeros(qt.shape, jnp.int32)
-        sz = m // 2
-        while sz >= 1:
-            mid = pos + (sz - 1)
-            go = lex_lt(lt[mid], lhi[mid], llo[mid], qt, qhi, qlo)
-            pos = jnp.where(go, pos + jnp.int32(sz), pos)
-            sz //= 2
-        go = lex_lt(lt[pos], lhi[pos], llo[pos], qt, qhi, qlo)
-        return pos + go.astype(jnp.int32)
-
     def interval_end(khi, klo, lev2):
         # Element keys are span-aligned, so the last key of the neighbor's
         # interval is key | (2^(d*(L-level)) - 1) — a dynamic-width mask
@@ -453,8 +463,14 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         cnt = jnp.concatenate(
             [jnp.zeros((L + 2, 1), jnp.int32), jnp.cumsum(incr, axis=1)], axis=1
         )
-        lo_i = lower_bound(lt, lhi, llo, tgt, khi, klo)
-        hi_i = lower_bound(lt, lhi, llo, tgt, kend.hi, kend.lo)
+        # The interval's first and one-past-last leaf, both bounds of every
+        # pair found by one merged sort with the table.
+        t = tgt.reshape(-1)
+        bounds = lex_lower_bound(
+            lt, lhi, llo, jnp.concatenate([t, t]),
+            jnp.concatenate([khi.reshape(-1), kend.hi.reshape(-1)]),
+            jnp.concatenate([klo.reshape(-1), kend.lo.reshape(-1)]))
+        lo_i, hi_i = bounds.reshape((2,) + tgt.shape)
         thr = jnp.minimum(lev2 + 2, L + 1)
         return (cnt[thr, hi_i] - cnt[thr, lo_i]) > 0
 

@@ -12,6 +12,7 @@ and ZERO jit retraces once a padding bucket is warm.
 
 import hashlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -114,6 +115,42 @@ def test_fused_eval_backend_parity(name, pattern, backend):
                 rp_d, rp_r, ("tree", "key", "level", "dual", "first", "last")):
             np.testing.assert_array_equal(
                 col_d, col_r, err_msg=f"route {what} rank {g}")
+
+
+_LEX = np.dtype([("tree", "<i4"), ("key", "<u8")])
+
+
+@pytest.mark.parametrize("trees", [1, 4])
+@pytest.mark.parametrize("rows", [1, 3, 16, 17])
+def test_lex_lower_bound_matches_searchsorted(rows, trees):
+    """The merged-sort lower bound of the 2:1 eval equals NumPy's
+    `searchsorted(side="left")` over (tree, key) on a leaf table padded with
+    sentinels as `upload_table` pads it: queries equal to every table row
+    (sentinels included), below every row, between the last leaf and the
+    sentinels, past the sentinels, and at random across the trees."""
+    rng = np.random.default_rng(rows * 10 + trees)
+    tree = np.sort(rng.integers(0, trees, rows)).astype(np.int32)
+    keys = rng.integers(1, 1 << 63, rows, dtype=np.uint64)
+    order = np.lexsort((keys, tree))
+    table = batch.get_batch_ops(3, "jnp").upload_table(
+        tree[order], keys[order], np.zeros(rows, np.int32))
+    lt, lhi, llo, _ = (np.asarray(a) for a in table.dev)
+    lk = (lhi.astype(np.uint64) << np.uint64(32)) | llo.astype(np.uint64)
+    imax, kmax = np.iinfo(np.int32).max, np.iinfo(np.uint64).max
+    qt = np.concatenate([
+        lt, np.array([0, -1, trees, imax, imax], np.int32),
+        rng.integers(0, trees + 1, 64, dtype=np.int32)])
+    qk = np.concatenate([
+        lk, np.array([0, kmax, 0, 1, kmax], np.uint64),
+        rng.integers(0, 1 << 63, 64, dtype=np.uint64)])
+    qk[-8:] = lk[rng.integers(0, len(lk), 8)]  # more exact hits, other trees
+    got = jax.jit(batch.lex_lower_bound)(
+        lt, lhi, llo, qt, (qk >> np.uint64(32)).astype(np.uint32),
+        qk.astype(np.uint32))
+    want = np.searchsorted(
+        np.rec.fromarrays([lt, lk], dtype=_LEX),
+        np.rec.fromarrays([qt, qk], dtype=_LEX), side="left")
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 @pytest.mark.parametrize("backend", ["reference"] + DEVICE_BACKENDS)

@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e chip.
+"""Ahead-of-time compiles of the main-path Pallas kernels, and of Balance's
+2:1 eval program, for a TPU v5e chip.
 
 Interpret mode (every other kernel test) cannot show what the TPU compiler
 refuses — a nested boolean select in the d=3 inside-root test once passed
@@ -102,6 +103,43 @@ def test_eval_route_compiles(one_chip):
         _i32(one_chip, pairs), _i32(one_chip, (8,)), _u32(one_chip, (8,)),
         _u32(one_chip, (8,)))
     assert "tpu_custom_call" in text
+
+
+@pytest.fixture(scope="module")
+def eval_need_texts(one_chip):
+    """Balance's 2:1 eval (`eval_need`) compiled for both element classes
+    over N elements and an N-row leaf table.  Its merged sort takes the TPU
+    compiler minutes on a CPU host, so the two compiles run side by side."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from repro.core import batch
+    from repro.core.ops import get_ops
+
+    def compile_one(eclass):
+        pairs = (get_ops(3, eclass).nf, N)
+        flags = jax.ShapeDtypeStruct(pairs, jnp.bool_, sharding=one_chip)
+        marker = (_i32(one_chip, ()), _u32(one_chip, ()), _u32(one_chip, ()),
+                  jax.ShapeDtypeStruct((), jnp.bool_, sharding=one_chip))
+        return batch._eval_progs(3, eclass)["need"].lower(
+            _i32(one_chip, pairs), _u32(one_chip, pairs), _u32(one_chip, pairs),
+            flags, _i32(one_chip),
+            _i32(one_chip), _u32(one_chip), _u32(one_chip), _i32(one_chip),
+            *marker, *marker).compile().as_text()
+
+    eclasses = (ECLASS_SIMPLEX, ECLASS_HEX)
+    with ThreadPoolExecutor(len(eclasses)) as pool:
+        return dict(zip(eclasses, pool.map(compile_one, eclasses)))
+
+
+@pytest.mark.parametrize("eclass", [ECLASS_SIMPLEX, ECLASS_HEX],
+                         ids=["simplex", "hex"])
+def test_eval_need_compiles(eval_need_texts, eclass):
+    """The eval's lower bounds are one merged sort, not a binary search,
+    which gathers three table words log2(N) times per (face, element)
+    pair: a sort, and no gathers beyond the level-count lookups."""
+    text = eval_need_texts[eclass]
+    assert " sort(" in text
+    assert text.count(" gather(") <= 4
 
 
 def test_tree_transform_compiles(one_chip):
