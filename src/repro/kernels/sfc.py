@@ -14,8 +14,12 @@ TPU adaptation (vs. the paper's scalar C):
     chains on vregs, which the VPU executes at full width.
   * The 64-bit consecutive index is carried as two uint32 words (TPU vector
     units have no 64-bit integer type); see `repro.core.u64`.
-  * Level loops are fully unrolled (MAXLEVEL is a compile-time constant), so
-    the kernel body is straight-line vector code with static shifts.
+  * Each per-level loop is one `lax.fori_loop` (`_encode_expr`,
+    `_decode_expr`), so Mosaic emits its body once, not MAXLEVEL times: the
+    unrolled d=3 face sweep's module text was 7x the hex one and took
+    seconds to lower per bucket.  A shift by the loop's level is clamped
+    to [0, 31]: a uint32 shift by 32 or more is not defined alike on every
+    backend.
 
 Each kernel has a pure-jnp oracle in `repro.kernels.ref` (delegating to
 `repro.core.ops`), and `repro.kernels.ops` wraps them with padding + jit.
@@ -95,41 +99,89 @@ def _packed_hex_nei(d: int):
 # ---------------------------------------------------- shared body expressions
 # The per-op kernel bodies below and the fused face-sweep body compose these
 # pure vreg->vreg expressions; keeping them shared means the fused kernel can
-# never drift from the single-op kernels it replaces.  Each simplex
-# expression has a hex twin (plain Morton: no type chain, axis-aligned
-# neighbors, box containment) selected statically by the bodies' `eclass`.
-def _encode_expr(d: int, coords, b):
-    """morton key (level-padded consecutive index) from Tet-id -> (hi, lo)."""
+# never drift from the single-op kernels it replaces.  Encode and decode
+# serve both classes (a hex digit is the raw cube id: no type chain); the
+# neighbor and inside expressions have hex twins (axis-aligned neighbors,
+# box containment).  The bodies' `eclass` selects statically.
+def _encode_expr(d: int, eclass: int, coords, b):
+    """morton key (level-padded consecutive index) from Tet-id -> (hi, lo).
+
+    Fine -> coarse over one rolled level loop (the type chain runs that
+    way); level i's digit goes to bit position d*(L-i), split over the two
+    words by masks on that dynamic position, every shift amount clamped to
+    [0, 31].  Hexes have no type chain: the digit is the raw cube id
+    (plain Morton), and `b` (all zero) rides along unread."""
     L = MAXLEVEL[d]
     enc, _, _ = _packed_tables(d)
     nc = 2 ** d
-    hi = jnp.zeros(b.shape, jnp.uint32)
-    lo = jnp.zeros(b.shape, jnp.uint32)
-    for i in range(L, 0, -1):  # fine -> coarse; positions are independent
+
+    def level(j, carry):  # j = L - i: the coordinate bit of level i
+        b, hi, lo = carry
         cid = jnp.zeros(b.shape, jnp.int32)
         for k, c in enumerate(coords):
-            cid = cid | (((c >> (L - i)) & 1) << k)
-        packed = _lut(enc, b * nc + cid)
-        iloc = (packed & 7).astype(jnp.uint32)
-        b = packed >> 3
-        pos = d * (L - i)
-        if pos < 32:
-            lo = lo | (iloc << pos)
-            if pos + d > 32:  # digit straddles the word boundary
-                hi = hi | (iloc >> (32 - pos))
+            cid = cid | (((c >> j) & 1) << k)
+        if eclass == ECLASS_HEX:
+            digit = cid
         else:
-            hi = hi | (iloc << (pos - 32))
+            packed = _lut(enc, b * nc + cid)
+            digit = packed & 7
+            b = packed >> 3
+        digit = digit.astype(jnp.uint32)
+        pos = d * j
+        # lo takes the digit's bits below 32, hi the rest (a straddling
+        # digit's top bits arrive shifted down by 32 - pos)
+        lo_mask = jnp.where(pos < 32, np.uint32(0xFFFFFFFF), np.uint32(0))
+        lo = lo | ((digit << jnp.minimum(pos, 31)) & lo_mask)
+        hi = hi | ((digit << jnp.maximum(pos - 32, 0)) >> jnp.clip(32 - pos, 0, 31))
+        return b, hi, lo
+
+    zero = jnp.zeros(b.shape, jnp.uint32)
+    _, hi, lo = jax.lax.fori_loop(0, L, level, (b, zero, zero))
     return hi, lo
+
+
+def _decode_expr(d: int, eclass: int, hi, lo, lvl):
+    """Element id from a morton key at the given level -> (coords, type),
+    the inverse of `_encode_expr`: coarse -> fine over one rolled level
+    loop, reading the key's top digit while the key shifts up by d.
+    Digits finer than `lvl` read as 0 (the caller supplies the level)."""
+    L = MAXLEVEL[d]
+    _, dec, _ = _packed_tables(d)
+    nc = 2 ** d
+    top = d * (L - 1) - 32
+
+    def level(j, carry):  # j = i - 1: level i, coarsest first
+        b, hi, lo, xyz = carry
+        digit = ((hi >> top) & np.uint32(nc - 1)).astype(jnp.int32)
+        active = j < lvl
+        iloc = jnp.where(active, digit, 0)
+        if eclass == ECLASS_HEX:
+            cid = iloc  # plain Morton: the digit IS the cube id
+        else:
+            packed = _lut(dec, b * nc + iloc)
+            cid = packed & 7
+            b = jnp.where(active, packed >> 3, b)
+        hi = (hi << d) | (lo >> (32 - d))
+        lo = lo << d
+        xyz = tuple((x << 1) | ((cid >> k) & 1) for k, x in enumerate(xyz))
+        return b, hi, lo, xyz
+
+    zero = jnp.zeros(hi.shape, jnp.int32)
+    b, _, _, xyz = jax.lax.fori_loop(0, L, level, (zero, hi, lo, (zero,) * d))
+    return xyz, b
 
 
 def _neighbor_expr(d: int, coords, lvl, b, f):
     """Same-level face neighbor (Algorithm 4.6) -> (coords', type', dual).
     `f` is a face-index vreg or a static Python int (the fused sweep unrolls
-    it statically)."""
+    it statically; a static face looks up only its own column of the table)."""
     L = MAXLEVEL[d]
     _, _, nei = _packed_tables(d)
     h = (jnp.int32(1) << (L - lvl)).astype(jnp.int32)
-    packed = _lut(nei, b * (d + 1) + f)
+    if isinstance(f, int):
+        packed = _lut(nei[f::d + 1], b)
+    else:
+        packed = _lut(nei, b * (d + 1) + f)
     out = []
     for k in range(d):
         off = ((packed >> (6 + 2 * k)) & 3) - 1
@@ -173,27 +225,6 @@ def _inside_expr(d: int, coords, lvl, b):
     return (at_root | ((lvl > 0) & inside)).astype(jnp.int32)
 
 
-def _hex_encode_expr(d: int, coords):
-    """Hex twin of `_encode_expr`: the plain Morton interleave — no type
-    chain, each level's digit is the raw cube id -> (hi, lo)."""
-    L = MAXLEVEL[d]
-    hi = jnp.zeros(coords[0].shape, jnp.uint32)
-    lo = jnp.zeros(coords[0].shape, jnp.uint32)
-    for i in range(L, 0, -1):
-        cid = jnp.zeros(coords[0].shape, jnp.int32)
-        for k, c in enumerate(coords):
-            cid = cid | (((c >> (L - i)) & 1) << k)
-        digit = cid.astype(jnp.uint32)
-        pos = d * (L - i)
-        if pos < 32:
-            lo = lo | (digit << pos)
-            if pos + d > 32:  # digit straddles the word boundary
-                hi = hi | (digit >> (32 - pos))
-        else:
-            hi = hi | (digit << (pos - 32))
-    return hi, lo
-
-
 def _hex_neighbor_expr(d: int, coords, lvl, f):
     """Hex twin of `_neighbor_expr`: neighbor across face f = 2*axis + dir
     is one cube side away along `axis`; dual = f ^ 1.  `f` is a face vreg or
@@ -231,51 +262,16 @@ def _encode_body(d: int, eclass: int, refs):
     else:
         x_ref, y_ref, b_ref, hi_ref, lo_ref = refs
         coords = (x_ref[...], y_ref[...])
-    if eclass == ECLASS_HEX:
-        hi_ref[...], lo_ref[...] = _hex_encode_expr(d, coords)
-    else:
-        hi_ref[...], lo_ref[...] = _encode_expr(d, coords, b_ref[...])
+    hi_ref[...], lo_ref[...] = _encode_expr(d, eclass, coords, b_ref[...])
 
 
 def _decode_body(d: int, eclass: int, refs):
     """Element id from morton key (level implied by trailing zero digits is
     NOT recovered here; the caller supplies it and we mask fine digits)."""
-    L = MAXLEVEL[d]
-    _, dec, _ = _packed_tables(d)
-    nc = 2 ** d
-    if d == 3:
-        hi_ref, lo_ref, lvl_ref, x_ref, y_ref, z_ref, b_ref = refs
-        nout = 3
-    else:
-        hi_ref, lo_ref, lvl_ref, x_ref, y_ref, b_ref = refs
-        nout = 2
-    hi = hi_ref[...]
-    lo = lo_ref[...]
-    lvl = lvl_ref[...]
-    b = jnp.zeros(hi.shape, jnp.int32)
-    xyz = [jnp.zeros(hi.shape, jnp.int32) for _ in range(nout)]
-    for i in range(1, L + 1):
-        pos = d * (L - i)
-        if pos >= 32:
-            digit = (hi >> (pos - 32)) & np.uint32(nc - 1)
-        elif pos + d > 32:
-            digit = ((lo >> pos) | (hi << (32 - pos))) & np.uint32(nc - 1)
-        else:
-            digit = (lo >> pos) & np.uint32(nc - 1)
-        iloc = jnp.where(i <= lvl, digit.astype(jnp.int32), 0)
-        if eclass == ECLASS_HEX:
-            cid = iloc  # plain Morton: the digit IS the cube id
-        else:
-            packed = _lut(dec, b * nc + iloc)
-            cid = packed & 7
-            b = jnp.where(i <= lvl, packed >> 3, b)
-        for k in range(nout):
-            xyz[k] = xyz[k] | (((cid >> k) & 1) << (L - i))
-    x_ref[...] = xyz[0]
-    y_ref[...] = xyz[1]
-    if d == 3:
-        z_ref[...] = xyz[2]
-    b_ref[...] = b
+    hi_ref, lo_ref, lvl_ref = refs[:3]
+    xyz, b = _decode_expr(d, eclass, hi_ref[...], lo_ref[...], lvl_ref[...])
+    for out_ref, v in zip(refs[3:], (*xyz, b)):
+        out_ref[...] = v
 
 
 def _neighbor_body(d: int, eclass: int, refs):
@@ -304,8 +300,8 @@ def _face_sweep_body(d: int, eclass: int, refs):
     2d hex), the same-level neighbor (coords/type/dual), its inside-root
     mask, and its morton key — the three ops Balance/Ghost evaluation
     composes per face, with the element's (anchor, level, type) read from
-    memory exactly once.  The face loop is a static unroll, so the body
-    stays straight-line vector code; every (field, face) pair is its own
+    memory exactly once.  The face loop is a static unroll around each
+    face's rolled encode loop; every (field, face) pair is its own
     lane-dense (block,) output (see `_lane_dense_outputs`)."""
     if d == 3:
         x_ref, y_ref, z_ref, lvl_ref, b_ref = refs[:5]
@@ -323,11 +319,10 @@ def _face_sweep_body(d: int, eclass: int, refs):
             ncoords, dual = _hex_neighbor_expr(d, coords, lvl, f)
             ntype = jnp.zeros(lvl.shape, jnp.int32)
             inside = _hex_inside_expr(d, ncoords, lvl)
-            hi, lo = _hex_encode_expr(d, ncoords)
         else:
             ncoords, ntype, dual = _neighbor_expr(d, coords, lvl, b, f)
             inside = _inside_expr(d, ncoords, lvl, ntype)
-            hi, lo = _encode_expr(d, ncoords, ntype)
+        hi, lo = _encode_expr(d, eclass, ncoords, ntype)
         for k in range(d):
             cols[k].append(ncoords[k])
         cols[d].append(ntype)
@@ -341,62 +336,21 @@ def _face_sweep_body(d: int, eclass: int, refs):
 
 
 def _successor_body(d: int, eclass: int, refs):
-    """Fused successor: encode -> +1 at own level -> decode (Algorithm 4.10).
-    The hex path skips the type-chain lookups on both sides (digit = cube
-    id) but shares the carry chain."""
+    """Fused successor (Algorithm 4.10): encode -> +1 at own level ->
+    decode, through the shared rolled expressions.  The key's digits below
+    `lvl` are zero, so adding 1 << d*(L-lvl) is the base-2^d carry chain;
+    a carry out of level 1 lands above the d*L key bits, which decode
+    never reads."""
     L = MAXLEVEL[d]
-    enc, dec, _ = _packed_tables(d)
-    nc = 2 ** d
-    is_hex = eclass == ECLASS_HEX
-    if d == 3:
-        x_ref, y_ref, z_ref, lvl_ref, b_ref, ox_ref, oy_ref, oz_ref, ob_ref = refs
-        coords = (x_ref[...], y_ref[...], z_ref[...])
-        nout = 3
-        outs = (ox_ref, oy_ref, oz_ref)
-    else:
-        x_ref, y_ref, lvl_ref, b_ref, ox_ref, oy_ref, ob_ref = refs
-        coords = (x_ref[...], y_ref[...])
-        nout = 2
-        outs = (ox_ref, oy_ref)
-    lvl = lvl_ref[...]
-    b = b_ref[...]
-    # --- encode iloc digits per level (store unrolled) ---
-    ilocs = [None] * (L + 1)
-    bb = b
-    for i in range(L, 0, -1):
-        cid = jnp.zeros(b.shape, jnp.int32)
-        for k, c in enumerate(coords):
-            cid = cid | (((c >> (L - i)) & 1) << k)
-        if is_hex:
-            ilocs[i] = cid
-        else:
-            packed = _lut(enc, bb * nc + cid)
-            ilocs[i] = packed & 7
-            bb = packed >> 3
-    # --- +1 with carry starting at own level (digits below lvl are zero) ---
-    carry = jnp.ones(b.shape, jnp.int32)
-    new_ilocs = [None] * (L + 1)
-    for i in range(L, 0, -1):
-        active = (i <= lvl)
-        s = ilocs[i] + jnp.where(active, carry, 0)
-        new_ilocs[i] = jnp.where(active, s % nc, ilocs[i])
-        carry = jnp.where(active, s // nc, carry)
-    # --- decode from new digits (coarse -> fine) ---
-    bo = jnp.zeros(b.shape, jnp.int32)
-    xyz = [jnp.zeros(b.shape, jnp.int32) for _ in range(nout)]
-    for i in range(1, L + 1):
-        iloc = jnp.where(i <= lvl, new_ilocs[i], 0)
-        if is_hex:
-            cid = iloc
-        else:
-            packed = _lut(dec, bo * nc + iloc)
-            cid = packed & 7
-            bo = jnp.where(i <= lvl, packed >> 3, bo)
-        for k in range(nout):
-            xyz[k] = xyz[k] | (((cid >> k) & 1) << (L - i))
-    for k in range(nout):
-        outs[k][...] = xyz[k]
-    ob_ref[...] = bo
+    coords = tuple(r[...] for r in refs[:d])
+    lvl = refs[d][...]
+    b = refs[d + 1][...]
+    hi, lo = _encode_expr(d, eclass, coords, b)
+    one = u64m.U64(jnp.zeros_like(hi), jnp.ones_like(lo))
+    key = u64m.add(u64m.U64(hi, lo), u64m.select_shl(one, d * (L - lvl), d * L))
+    xyz, bo = _decode_expr(d, eclass, key.hi, key.lo, lvl)
+    for out_ref, v in zip(refs[d + 2:], (*xyz, bo)):
+        out_ref[...] = v
 
 
 def _parent_body(d: int, eclass: int, refs):

@@ -7,6 +7,7 @@ import pytest
 from _helpers import rand_simplices
 from repro.core import u64 as u64m
 from repro.core.ops import get_ops
+from repro.core.types import ECLASS_HEX, ECLASS_SIMPLEX
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
@@ -146,6 +147,40 @@ def test_owner_rank_kernel(d, n):
         np.asarray(t), np.asarray(key.hi), np.asarray(key.lo),
         mt_p, np.asarray(mkey.hi), np.asarray(mkey.lo))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("eclass", [ECLASS_SIMPLEX, ECLASS_HEX], ids=["simplex", "hex"])
+def test_key_kernels_at_level_extremes(eclass):
+    """The rolled level loops at their ends, d = 3: level 0 (no digit),
+    level 21 (every digit, the last element's successor wrapping to the
+    first), and the level-11 digit at bits 30-32 that straddles the two
+    key words, each of its bits alone, all set and all clear."""
+    d = 3
+    o = get_ops(d, eclass)
+    L = o.L
+    straddle = [b << 30 for b in (1, 2, 4, 7)]
+    at_l = [0, 1, (1 << 63) - 1, (1 << 63) - 2, (1 << 30) - 1, 1 << 33,
+            (1 << 63) - 1 - (7 << 30)] + straddle
+    ids = [0] + at_l + [k >> 3 * (L - 11) for k in straddle + [(1 << 63) - 1]]
+    lv = [0] + [L] * len(at_l) + [11] * 5
+    s = o.from_linear_id(u64m.from_int(np.array(ids, np.uint64)),
+                         jnp.asarray(lv, jnp.int32))
+
+    key = kops.morton_key(d, s, eclass=eclass)
+    ref_key = o.morton_key(s)
+    np.testing.assert_array_equal(np.asarray(key.hi), np.asarray(ref_key.hi))
+    np.testing.assert_array_equal(np.asarray(key.lo), np.asarray(ref_key.lo))
+    for got, want in ((kops.decode(d, ref_key, s.level, eclass=eclass), s),
+                      (kops.successor(d, s, eclass=eclass), o.successor(s))):
+        np.testing.assert_array_equal(np.asarray(got.anchor), np.asarray(want.anchor))
+        np.testing.assert_array_equal(np.asarray(got.stype), np.asarray(want.stype))
+    fields = [s.anchor[..., k] for k in range(d)]
+    want = kref.face_sweep_ref(d, *fields, s.level, s.stype, eclass=eclass)
+    nb, dual, inside, key = kops.face_sweep(d, s, eclass=eclass)
+    got = (*[nb.anchor[..., k].T for k in range(d)], nb.stype.T, dual.T,
+           inside.astype(jnp.int32).T, key.hi.T, key.lo.T)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 @pytest.mark.slow

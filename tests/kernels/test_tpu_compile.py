@@ -70,6 +70,36 @@ def test_face_sweep_compiles(one_chip, eclass):
     assert "tpu_custom_call" in text
 
 
+def test_face_sweep_lowering_stays_small(one_chip):
+    """Each per-level loop is one rolled loop, so the d=3 simplex sweep
+    lowers to about the hex sweep's size.  Unrolled over its 21 levels, the
+    type chain's table lookups made it 7x the hex module text and seconds
+    of lowering per bucket, before the persistent cache is asked."""
+    d = 3
+    shapes = [_i32(one_chip) for _ in range(d + 2)]
+    size = {
+        eclass: len(jax.jit(
+            lambda *a, e=eclass: sfc.face_sweep_kernel(d, *a, block=BLOCK, interpret=False,
+                                                       eclass=e)).lower(*shapes).as_text())
+        for eclass in (ECLASS_SIMPLEX, ECLASS_HEX)}
+    assert size[ECLASS_SIMPLEX] <= 2 * size[ECLASS_HEX]
+
+
+@pytest.mark.parametrize("eclass", [ECLASS_SIMPLEX, ECLASS_HEX],
+                         ids=["simplex", "hex"])
+@pytest.mark.parametrize("kernel", ["morton_key", "successor"])
+def test_key_kernels_compile(one_chip, kernel, eclass):
+    """The rolled encode loop's carry (type, key words) and the successor's
+    encode -> add -> decode chain, through Mosaic."""
+    d = 3
+    fn = {"morton_key": sfc.morton_key_kernel, "successor": sfc.successor_kernel}[kernel]
+    n_in = d + 1 if kernel == "morton_key" else d + 2
+    text = _compile_text(
+        lambda *a: fn(d, *a, block=BLOCK, interpret=False, eclass=eclass),
+        *[_i32(one_chip) for _ in range(n_in)])
+    assert "tpu_custom_call" in text
+
+
 def test_inside_root_compiles(one_chip):
     d = 3
     text = _compile_text(
