@@ -340,10 +340,11 @@ def lex_lower_bound(lt, lhi, llo, qt, qhi, qlo):
 # ------------------------------------------------------- device-resident eval
 # The fused Balance/Ghost eval stage.  A round's evaluation is three device
 # programs over ONE resident face sweep — need-mask vs the local leaf table,
-# need-mask vs the remote-leaf cache, and boundary query routing — with the
-# host only slicing the compacted routing rows to build wire triples.  The
-# reference backend runs the same algorithms eagerly in numpy and is the
-# bit-identical oracle.
+# need-mask vs the remote-leaf cache, and boundary query routing (a count
+# program, then a gather of the remote rows only when there are some) —
+# with the host only slicing the compacted routing rows to build wire
+# triples.  The reference backend runs the same algorithms eagerly in numpy
+# and is the bit-identical oracle.
 
 
 class SweepHandle(NamedTuple):
@@ -527,26 +528,14 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         finer = finer_mask(tgt, khi, klo, lev2, kend, lt, lhi, llo, llev)
         return jnp.any(evalp & finer, axis=0)
 
-    def route_pack(t, khi, klo, lev, dual, first, last, remote):
-        # Cumsum-scatter compaction: remote rows land densely at the front,
-        # non-remote lanes dump into the extra row sz (never read — the
-        # host slices [:count]).  Flattened C-order of the face-major
-        # (nf, m) `remote` keeps the row order of the host oracle's
-        # np.nonzero.  The running count is taken per face row plus the
-        # rows before it: one cumsum over the flattened mask compiles
-        # about 7x slower on a TPU at 2^23 elements.
-        sz = t.shape[0]
-        run = jnp.cumsum(remote.astype(jnp.int32), axis=1)
-        total = run[:, -1]
-        idx = (run + (jnp.cumsum(total) - total)[:, None] - 1).reshape(-1)
-        scat = jnp.where(remote.reshape(-1), idx, jnp.int32(sz))
-        cols = jnp.stack(
-            [t, khi.astype(jnp.int32), klo.astype(jnp.int32),
-             lev, dual, first, last], axis=1)
-        packed = jnp.zeros((sz + 1, 7), jnp.int32).at[scat].set(cols)
-        return total.sum(), packed
+    def remote_mask(valid, first, last, g):
+        # Pairs whose neighbor interval reaches outside rank g's partition.
+        return valid & ((first != g) | (last != g)).reshape(valid.shape)
 
     def eval_route(tgt, khi, klo, valid, dual, lev, mt, mhi, mlo, g):
+        # The count program: owner ranks of every pair and how many are
+        # remote.  `first` / `last` stay on the device for `route_gather`,
+        # which runs only when the count is not 0.
         telemetry.count("trace.eval_route")
         m = lev.shape[0]
         lev2 = jnp.broadcast_to(lev[None, :], (nf, m))
@@ -555,9 +544,7 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         first = owner_rank_lex(tf, hf, lf, mt, mhi, mlo)
         last = owner_rank_lex(
             tf, kh.hi.reshape(-1), kh.lo.reshape(-1), mt, mhi, mlo)
-        remote = valid & ((first != g) | (last != g)).reshape(valid.shape)
-        return route_pack(tf, hf, lf, lev2.reshape(-1), dual.reshape(-1),
-                          first, last, remote)
+        return remote_mask(valid, first, last, g).sum(), first, last
 
     def eval_route_pallas(tgt, khi, klo, valid, dual, lev, mt, mhi, mlo, g):
         telemetry.count("trace.eval_route_pallas")
@@ -567,11 +554,31 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         lev2 = jnp.broadcast_to(lev[None, :], (nf, m))
         _hh, _hl, first, last = kops.eval_route(
             d, tgt, khi, klo, lev2, mt, mhi, mlo, min(1024, m))
-        remote = valid & ((first != g) | (last != g))
         first, last = first.reshape(-1), last.reshape(-1)
-        return route_pack(tgt.reshape(-1), khi.reshape(-1), klo.reshape(-1),
-                          lev2.reshape(-1), dual.reshape(-1),
-                          first, last, remote)
+        return remote_mask(valid, first, last, g).sum(), first, last
+
+    def route_gather(tgt, khi, klo, valid, dual, lev, first, last, g, cap):
+        # Compaction of the remote pairs into `cap` rows: output row j is
+        # the pair where the running count of the flattened C-order of the
+        # face-major (nf, m) mask first reaches j + 1, which keeps the row
+        # order of the host oracle's np.nonzero.  The running count is
+        # taken per face row plus the rows before it: one cumsum over the
+        # flattened mask compiles about 7x slower on a TPU at 2^23
+        # elements.  Rows past the count repeat the last pair (the host
+        # slices [:count]).
+        telemetry.count("trace.route_gather")
+        m = lev.shape[0]
+        run = jnp.cumsum(
+            remote_mask(valid, first, last, g).astype(jnp.int32), axis=1)
+        total = run[:, -1]
+        run = (run + (jnp.cumsum(total) - total)[:, None]).reshape(-1)
+        src = jnp.searchsorted(
+            run, jnp.arange(1, cap + 1, dtype=jnp.int32), side="left")
+        src = jnp.minimum(src, nf * m - 1)
+        return jnp.stack(
+            [tgt.reshape(-1)[src], khi.reshape(-1)[src].astype(jnp.int32),
+             klo.reshape(-1)[src].astype(jnp.int32), lev[src % m],
+             dual.reshape(-1)[src], first[src], last[src]], axis=1)
 
     return {
         "sweep": jax.jit(sweep),
@@ -580,6 +587,7 @@ def _eval_progs(d: int, eclass: int = ECLASS_SIMPLEX):
         "cache": jax.jit(eval_cache),
         "route": jax.jit(eval_route),
         "route_pallas": jax.jit(eval_route_pallas),
+        "route_gather": jax.jit(route_gather, static_argnames="cap"),
     }
 
 
@@ -1035,8 +1043,12 @@ class BatchedOps:
     def eval_route(self, sw: SweepHandle | None, mt, mk, g: int) -> RoutePairs:
         """Fused boundary routing: compact the (face, element) pairs whose
         neighbor interval reaches outside rank g's partition, with the
-        [first, last] owner-rank range per pair.  The host receives ONE
-        (count, rows) materialization and builds wire triples from it."""
+        [first, last] owner-rank range per pair.  One program counts the
+        remote pairs; only when the count is not 0 does a second one
+        gather them into a `_bucket(count)` buffer.  The host fetches the
+        count, then those rows, inside ONE `fetch` span, whose stats carry
+        the pairs found remote (`pairs_remote`) and swept (`pairs_swept`),
+        and builds wire triples from the rows."""
         if sw is None or sw.n == 0:
             return _empty_route()
         mt = np.asarray(mt, np.int32)
@@ -1058,15 +1070,19 @@ class BatchedOps:
                 lev[ei[sel]].astype(np.int32), dual[fi, ei][sel].astype(np.int32),
                 first[sel], last[sel])
         mt_j, mkey = _padded_markers_cached(mt, mk)
-        prog = "route" if which == "jnp" else "route_pallas"
-        cnt, packed = _eval_progs(self.d, self.eclass)[prog](
-            *sw.dev, mt_j, mkey.hi, mkey.lo, _rank_scalar(g))
+        progs = _eval_progs(self.d, self.eclass)
+        gd = _rank_scalar(g)
+        cnt, first, last = progs["route" if which == "jnp" else "route_pallas"](
+            *sw.dev, mt_j, mkey.hi, mkey.lo, gd)
         telemetry.count("fetch.eval_route")
-        with fetch_span("eval_route"):
+        with fetch_span("eval_route", pairs_swept=first.shape[0]) as sp:
             c = int(cnt)
+            sp.set_metadata(pairs_remote=c)
             if c == 0:
                 return _empty_route()
-            arr = np.asarray(packed[:c])
+            telemetry.count("route.compactions")
+            arr = np.asarray(progs["route_gather"](
+                *sw.dev, first, last, gd, cap=_bucket(c)))[:c]
         khi = np.asarray(arr[:, 1], np.int64) & np.int64(0xFFFFFFFF)
         klo = np.asarray(arr[:, 2], np.int64) & np.int64(0xFFFFFFFF)
         key = (khi.astype(np.uint64) << np.uint64(32)) | klo.astype(np.uint64)

@@ -1554,10 +1554,10 @@ def ghost(forests: list[Forest], comm: Comm, overlap: bool = True) -> list[dict]
 
     The routing pass is *device resident*: one fused face sweep per
     non-empty rank stays on the backend as a `SweepHandle` and the fused
-    `BatchedOps.eval_route` program compacts the remote-reaching (face,
-    element) pairs with their [first, last] owner-rank ranges — the host
-    slices exactly ONE (count, rows) materialization per rank and packs
-    wire quads from it.
+    `BatchedOps.eval_route` programs count the remote-reaching (face,
+    element) pairs and, when there are some, compact them with their
+    [first, last] owner-rank ranges — the host fetches the count and those
+    rows in ONE fetch per rank and packs wire quads from them.
 
     Both alltoallv stages are double buffered (`overlap=False` completes
     every collective at its post site — the serialized baseline): the

@@ -16,10 +16,14 @@ Counters are integers keyed `<prefix>.<name>`:
   dispatch.<op>      batched element-op dispatches (`batch.dispatch_counts`)
   trace.<program>    jit traces of the fused eval programs (`batch.trace_counts`)
   fetch.<stage>      device->host fetches of the fused eval (`batch.host_fetch_counts`)
+  route.compactions  runs of the routing gather, one per `eval_route` call
+                     that found remote (face, element) pairs
 
 Balance's rounds and the 2:1 eval's (face, element) pairs are read from the
 spans: one `balance.round` per eval round, and the `pairs_live` /
-`pairs_padded` stats of the eval's `fetch` spans.
+`pairs_padded` stats of the eval's `fetch` spans.  The routing eval's
+`fetch` span carries `pairs_remote` (the pairs it found remote) and
+`pairs_swept` (nf x bucket, the pairs it ran on).
 """
 
 from __future__ import annotations

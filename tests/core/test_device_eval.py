@@ -18,11 +18,12 @@ import numpy as np
 import pytest
 
 from _helpers import rand_simplices
-from repro.core import batch, get_ops
+from repro.core import batch, get_ops, telemetry
 from repro.core import cmesh as C
 from repro.core import forest as F
 from repro.core import u64 as u64m
 from repro.core.types import Simplex
+from test_telemetry import _recorded
 
 DEVICE_BACKENDS = ["jnp", pytest.param("pallas", marks=pytest.mark.slow)]
 
@@ -223,6 +224,106 @@ def test_balance_and_ghost_do_not_retrace(backend):
         traces = batch.trace_counts()
         assert all(v == 0 for v in traces.values()), traces
         batch.reset_trace_counts()
+
+
+# Three ranks' markers over trees 0..2; rank 3 owns nothing, so for it every
+# valid (face, element) pair is remote and the mask alone picks the rows.
+_MT = np.array([0, 1, 1], np.int32)
+_MK = np.array([0, 0, 1 << 40], np.uint64)
+_ROUTE_N = 40
+ROUTE_MASKS = ["none", "all", "first_lane", "last_lane", "random",
+               "count_32", "count_33"]
+
+
+def _route_mask(kind, nf, n=_ROUTE_N):
+    """A face-major (nf, n) pair mask; `count_<c>` sets c random pairs."""
+    rng = np.random.default_rng(1)
+    mask = np.zeros((nf, n), bool)
+    if kind == "all":
+        mask[:] = True
+    elif kind == "first_lane":
+        mask[0, 0] = True
+    elif kind == "last_lane":
+        mask[-1, -1] = True
+    elif kind == "random":
+        mask = rng.random(mask.shape) < 0.3
+    elif kind.startswith("count_"):
+        mask.flat[rng.choice(mask.size, int(kind[6:]), replace=False)] = True
+    return mask
+
+
+def _route_sweep(bops, valid):
+    """A synthetic resident sweep whose valid pairs are exactly `valid`:
+    random target trees, dual faces and levels, span-aligned keys."""
+    nf, n = valid.shape
+    d, L = bops.d, bops.ops.L
+    rng = np.random.default_rng(2)
+    lev = rng.integers(1, L + 1, n).astype(np.int32)
+    shift = np.uint64(d) * (np.uint64(L) - lev.astype(np.uint64))
+    key = (rng.integers(0, 1 << (d * L), (nf, n), dtype=np.uint64)
+           >> shift) << shift
+    tgt = rng.integers(0, 3, (nf, n)).astype(np.int32)
+    dual = rng.integers(0, nf, (nf, n)).astype(np.int32)
+    return bops.sweep_from_host(tgt, key, valid, dual, lev)
+
+
+@pytest.mark.parametrize("backend", DEVICE_BACKENDS)
+@pytest.mark.parametrize("kind", ROUTE_MASKS)
+def test_route_compaction_matches_nonzero(kind, backend):
+    """The routing compaction alone: the rows of the remote pairs come out
+    in the C-order of NumPy's `np.nonzero` over the face-major mask, column
+    by column — with no pair, every pair, only the first lane, only the
+    last lane of the last face row, a random mask, and 2^5 and 2^5 + 1
+    pairs at a `_bucket` edge."""
+    bops = batch.get_batch_ops(2, backend)
+    valid = _route_mask(kind, bops.nf)
+    sw = _route_sweep(bops, valid)
+    tgt, key, _valid, dual, lev = sw.host
+    got = _route_rows(bops.eval_route(sw, _MT, _MK, len(_MT)))
+    fi, ei = np.nonzero(valid)
+    t, k = tgt[fi, ei], key[fi, ei]
+    end = k + batch._spans_np(2, bops.ops.L, lev[ei]) - np.uint64(1)
+    want = (t, k, lev[ei], dual[fi, ei],
+            batch._owner_np(t, k, _MT, _MK), batch._owner_np(t, end, _MT, _MK))
+    assert len(got[0]) == valid.sum()
+    for col_g, col_w, what in zip(
+            got, want, ("tree", "key", "level", "dual", "first", "last")):
+        np.testing.assert_array_equal(col_g, col_w, err_msg=what)
+
+
+def test_route_gather_traces_once_per_cap():
+    """Counts that share a `_bucket` capacity share one compiled gather, so
+    a warm cell compiles nothing when its boundary count moves in a
+    bucket; the next bucket traces once more.  The sweep's bucket (128
+    elements) is one no other test of this module runs the gather at."""
+    bops = batch.get_batch_ops(2, "jnp")
+    batch.reset_trace_counts()
+    telemetry.reset_counts("route.")
+    for c in (17, 24, 32, 33):
+        valid = _route_mask(f"count_{c}", bops.nf, n=100)
+        rp = bops.eval_route(_route_sweep(bops, valid), _MT, _MK, len(_MT))
+        assert len(rp.tree) == c
+        assert batch.trace_counts()["route_gather"] == (1 if c <= 32 else 2)
+    assert telemetry.counts("route.") == {"compactions": 4}
+
+
+@pytest.mark.filterwarnings("ignore::DeprecationWarning")
+@pytest.mark.parametrize("P", [1, 3])
+@pytest.mark.parametrize("name", sorted(FIXTURES))
+def test_route_compacts_only_remote_pairs(name, P, tmp_path):
+    """A Balance and Ghost cycle runs the routing gather once per
+    `eval_route` call whose count is not 0, as the `fetch` spans'
+    `pairs_remote` stats read: at one rank no pair is remote and nothing
+    is compacted; at three ranks the boundary pairs are."""
+    with batch.use_backend("jnp"):
+        fs, comm = _mk_forests(name, P, "corner")
+        telemetry.reset_counts("route.")
+        spans = _recorded(tmp_path, lambda: F.ghost(F.balance(fs, comm), comm))
+    routes = [s[3] for s in spans if s[0] == "fetch" and s[3]["site"] == "eval_route"]
+    found = sum(1 for st in routes if st["pairs_remote"] > 0)
+    assert routes and all(st["pairs_remote"] <= st["pairs_swept"] for st in routes)
+    assert telemetry.counts("route.").get("compactions", 0) == found
+    assert found == 0 if P == 1 else found > 0
 
 
 @pytest.mark.parametrize("d", [2, 3])
